@@ -198,7 +198,7 @@ def group_domain(reader, column: str) -> int:
 class DatapathEngine:
     def __init__(
         self,
-        backend: str = "ref",
+        backend: str = "auto",
         offload: str = "raw",
         cache: Optional[BlockCache] = None,
     ):

@@ -79,8 +79,8 @@ def q1(engine: DatapathEngine, readers: Dict, delta_days: int = 90) -> dict:
 # ---------------------------------------------------------------------------
 
 
-def q6(engine: DatapathEngine, readers: Dict, year_start: int = 365) -> dict:
-    plan = ScanPlan(
+def q6_plan(year_start: int = 365) -> ScanPlan:
+    return ScanPlan(
         "lineitem",
         ["l_extendedprice", "l_discount"],
         and_(
@@ -89,9 +89,17 @@ def q6(engine: DatapathEngine, readers: Dict, year_start: int = 365) -> dict:
             Cmp("l_quantity", "lt", 24),
         ),
     )
-    res = engine.scan(readers["lineitem"], plan)
+
+
+def q6_answer(res) -> dict:
+    """Q6's answer from the ScanResult of `q6_plan` (callers that submit
+    the scan themselves, e.g. several tenants at once, finish with this)."""
     rev = _msum(res.columns["l_extendedprice"] * res.columns["l_discount"], res.mask)
     return {"revenue": float(rev), "rows": int(res.count)}
+
+
+def q6(engine: DatapathEngine, readers: Dict, year_start: int = 365) -> dict:
+    return q6_answer(engine.scan(readers["lineitem"], q6_plan(year_start)))
 
 
 # ---------------------------------------------------------------------------

@@ -65,7 +65,7 @@ class TokenPipeline:
         self.B, self.S = batch_size, seq_len
         self.mode = mode
         self.quality_min = quality_min
-        self.engine = engine or DatapathEngine(backend="ref", offload="preloaded")
+        self.engine = engine or DatapathEngine(backend="auto", offload="preloaded")
         self.state = state or PipelineState()
         self._pool: Optional[jax.Array] = None  # device-resident token pool
         self._pool_np: Optional[np.ndarray] = None

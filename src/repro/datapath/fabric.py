@@ -106,7 +106,7 @@ class ScanFabric:
     def __init__(
         self,
         n_pods: int = 2,
-        backend: str = "ref",
+        backend: str = "auto",
         cost_model: Optional[CostModel] = None,
         catalog: Optional[Catalog] = None,
         quotas: Optional[Dict[str, TenantQuota]] = None,

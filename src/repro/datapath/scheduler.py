@@ -63,6 +63,7 @@ from typing import Dict, List, Tuple
 from repro.core import engine as _engine_mod
 from repro.core.engine import ResumableScan
 from repro.datapath import trace
+from repro.datapath.faults import CorruptPageError, StorageFault
 from repro.datapath.policy import coalesce_compatible
 
 # Install the engine's flight-recorder hook (engine.TRACE).  The engine
@@ -447,8 +448,8 @@ def _run_group_stacked(service, group, pool, fetches) -> None:
     set of buckets (engine.scan_group_batched): a page two requests both
     need decodes once, launches drop again by the stacking factor, and
     the engine's strict item ordering keeps results AND accounting
-    bit-identical to the sequential per-request dispatch.  If the group
-    pass itself fails, every request falls back to its own
+    bit-identical to the sequential per-request dispatch.  If one request
+    poisons the group pass, every request falls back to its own
     `advance_batched` (per-request fault isolation is preserved either
     way — one poisoned request never takes down its partners)."""
     tel = service.telemetry
@@ -507,7 +508,11 @@ def _run_group_stacked(service, group, pool, fetches) -> None:
             results = engine.scan_group_batched(items, pool=pool)
             tel.inc("xreq_groups")
             tel.inc("xreq_requests", len(items))
-        except Exception:  # noqa: BLE001 — fall back to per-request dispatch
+        except (StorageFault, CorruptPageError, ConnectionError, KeyError):
+            # one request's storage fault, or a plan naming a column its
+            # table lacks: rerun each request alone so that one fails by
+            # itself.  A lowering or device error is no request's fault
+            # and raises.
             results = None
             tel.inc("xreq_fallback")
 

@@ -198,7 +198,7 @@ class Pod:
         assert scheduler in ("wfq", "fifo"), scheduler
         self.pod_id = pod_id
         assert hold_ticks == "auto" or int(hold_ticks) >= 0, hold_ticks
-        self.engine = engine or DatapathEngine(backend="ref", cache=BlockCache())
+        self.engine = engine or DatapathEngine(backend="auto", cache=BlockCache())
         self.max_queue_depth = max_queue_depth
         self.batch_per_tick = batch_per_tick
         self.quota_window_ticks = quota_window_ticks

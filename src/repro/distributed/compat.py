@@ -1,58 +1,28 @@
-"""jax version-compat shims for the distributed runtime.
+"""Mesh and shard_map helpers shared by the distributed runtime.
 
-The distributed code targets the stable mesh/shard_map surface newer jax
-exposes at the top level (`jax.shard_map`, `jax.set_mesh`, mesh axis
-types), but the pinned jax here still spells those
-`jax.experimental.shard_map` (with `check_rep` instead of `check_vma`)
-and enters a mesh through the `Mesh` context manager.  Everything that
-needs one of the moved APIs goes through this module so the version
-split lives in exactly one place.
+Everything that builds a mesh or enters one goes through this module, so
+the choice of axis types lives in exactly one place.
 """
 
 from __future__ import annotations
 
-from typing import Callable
-
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
-try:  # jax >= 0.6 moved shard_map to the top level
-    shard_map: Callable = jax.shard_map
-    _NOCHECK = {"check_vma": False}
-except AttributeError:  # pragma: no cover - version-dependent branch
-    from jax.experimental.shard_map import shard_map  # type: ignore
-
-    _NOCHECK = {"check_rep": False}
+shard_map = jax.shard_map
+axis_size = jax.lax.axis_size
+use_mesh = jax.set_mesh
 
 
 def shard_map_nocheck(f, mesh, in_specs, out_specs):
-    """`shard_map` with replication checking off — the flag newer jax
-    names `check_vma` and older jax `check_rep`."""
+    """`shard_map` with replication checking off."""
     return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     **_NOCHECK)
-
-
-def axis_size(name) -> int:
-    """Size of a named mesh axis from inside shard_map — `jax.lax.
-    axis_size` where it exists, else `psum(1, name)`, which older jax
-    constant-folds to the same static size."""
-    size = getattr(jax.lax, "axis_size", None)
-    if size is not None:
-        return size(name)
-    return jax.lax.psum(1, name)
+                     check_vma=False)
 
 
 def make_mesh(axis_shapes, axis_names) -> Mesh:
-    """`jax.make_mesh` minus the `axis_types` kwarg newer callers pass:
-    explicitly-Auto axes are the default everywhere, and older jax has no
-    `jax.sharding.AxisType` to spell them with."""
-    return jax.make_mesh(axis_shapes, axis_names)
-
-
-def use_mesh(mesh: Mesh):
-    """Context manager making `mesh` current: `jax.set_mesh` on newer
-    jax, the `Mesh` context manager on older."""
-    setter = getattr(jax, "set_mesh", None)
-    if setter is not None:
-        return setter(mesh)
-    return mesh
+    """`jax.make_mesh` with every axis Auto: the sharding code places
+    arrays with `NamedSharding` and lets the compiler propagate, which
+    Explicit axes (the default `jax.make_mesh` picks) would reject."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_shapes))

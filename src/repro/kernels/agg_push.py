@@ -14,11 +14,12 @@ so many row groups share ONE launch per (encoding, k, dtype) bucket:
                        outside VMEM — the result DMA is (nblocks, 1)
                        accumulators instead of (nblocks, 4096) values
 
-Both mirror `kernels/ref.py` `grouped_agg` op-for-op (the kernel bodies
-call the same block math), so parity is exact and every reduction stays
-within a block: grid steps, bucket splits, row-group slices and pod
-shards all produce bit-identical partial rows, and the host-side int64 /
-float64 merge (core/agg.py) is order-independent by exactness.
+Both run `kernels/ref.py` `grouped_agg_tiles` as their body (the oracle
+`grouped_agg` runs it too), on each block in its (32, 128) tile layout, so
+parity is exact and every reduction stays within a block: grid steps,
+bucket splits, row-group slices and pod shards all produce bit-identical
+partial rows, and the host-side int64 / float64 merge (core/agg.py) is
+order-independent by exactness.
 """
 
 from __future__ import annotations
@@ -30,11 +31,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.bitunpack import _ladder
-from repro.kernels.ref import grouped_agg
-from repro.lakeformat.encodings import LANES, PACK_BLOCK
+from repro.kernels.ref import grouped_agg_tiles
+from repro.lakeformat.encodings import LANES, SUBLANES
 
-# (group, 4096, n_groups) one-hot intermediates bound VMEM: group=2 at
-# the MAX_GROUPS ceiling stays ~4 MB per intermediate
+# blocks per grid step; the body loops over groups, so every intermediate
+# stays (group, 32, 128) whatever n_groups is
 DEFAULT_GROUP = 2
 MAX_GROUPS = 128  # pushdown eligibility ceiling (engine falls back above)
 
@@ -43,16 +44,43 @@ def _out_shapes(nblocks: int, n_groups: int, vdtype):
     """ShapeDtypeStructs for the 5 accumulator planes (cnt/s0/s1/mn/mx)."""
     sum_dt = jnp.float32 if jnp.issubdtype(vdtype, jnp.floating) else jnp.int32
     dts = (jnp.int32, sum_dt, jnp.int32, vdtype, vdtype)
-    return [jax.ShapeDtypeStruct((nblocks, n_groups), dt) for dt in dts]
+    return [jax.ShapeDtypeStruct((nblocks, 1, n_groups), dt) for dt in dts]
 
 
-def _grouped_kernel(n_groups, vals_ref, gids_ref, mask_ref,
-                    cnt_ref, s0_ref, s1_ref, mn_ref, mx_ref):
-    cnt, s0, s1, mn, mx = grouped_agg(
-        vals_ref[...], gids_ref[...], mask_ref[...], n_groups
-    )
-    cnt_ref[...], s0_ref[...], s1_ref[...] = cnt, s0, s1
-    mn_ref[...], mx_ref[...] = mn, mx
+def _write(out_refs, planes):
+    for ref, plane in zip(out_refs, planes):
+        ref[...] = plane
+
+
+def _grouped_kernel(n_groups, vals_ref, gids_ref, mask_ref, *out_refs):
+    _write(out_refs, grouped_agg_tiles(
+        vals_ref[...], gids_ref[...], mask_ref[...], n_groups))
+
+
+def _tiles(x: jax.Array) -> jax.Array:
+    return x.reshape(x.shape[0], SUBLANES, LANES)
+
+
+def _pad_rows(x: jax.Array, pad: int) -> jax.Array:
+    return jnp.pad(x, [(0, pad)] + [(0, 0)] * (x.ndim - 1)) if pad else x
+
+
+def _agg_call(kernel, nblocks: int, group: int, in_blocks, n_groups: int,
+              vdtype, interpret: bool, *args):
+    """One pallas_call over padded (nb, ...) inputs -> 5 (nblocks, n_groups)
+    planes; padded blocks carry mask == 0, so their rows are identities."""
+    nb = args[0].shape[0]
+    gspec = pl.BlockSpec((group, 1, n_groups), lambda i: (i, 0, 0))
+    outs = pl.pallas_call(
+        kernel,
+        grid=(nb // group,),
+        in_specs=[pl.BlockSpec((group,) + b, lambda i, n=len(b): (i,) + (0,) * n)
+                  for b in in_blocks],
+        out_specs=[gspec] * 5,
+        out_shape=_out_shapes(nb, n_groups, vdtype),
+        interpret=interpret,
+    )(*args)
+    return tuple(o.reshape(nb, n_groups)[:nblocks] for o in outs)
 
 
 @functools.partial(jax.jit, static_argnames=("n_groups", "group", "interpret"))
@@ -63,7 +91,7 @@ def grouped_agg_pallas(
     n_groups: int,
     *,
     group: int = DEFAULT_GROUP,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """values (nblocks, 4096) int32|float32; gids/mask (nblocks, 4096)
     int32 -> 5 x (nblocks, n_groups): cnt, s0, s1, mn, mx (ref.grouped_agg
@@ -72,33 +100,17 @@ def grouped_agg_pallas(
     nblocks = values.shape[0]
     group = min(group, nblocks)
     pad = (-nblocks) % group
-    if pad:
-        values = jnp.pad(values, ((0, pad), (0, 0)))
-        gids = jnp.pad(gids, ((0, pad), (0, 0)))
-        mask = jnp.pad(mask, ((0, pad), (0, 0)))  # zeros -> identity rows
-    steps = values.shape[0] // group
-    out_shape = _out_shapes(values.shape[0], n_groups, values.dtype)
-    spec = pl.BlockSpec((group, PACK_BLOCK), lambda i: (i, 0))
-    gspec = pl.BlockSpec((group, n_groups), lambda i: (i, 0))
-    outs = pl.pallas_call(
-        functools.partial(_grouped_kernel, n_groups),
-        grid=(steps,),
-        in_specs=[spec, spec, spec],
-        out_specs=[gspec] * 5,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(values, gids.astype(jnp.int32), mask.astype(jnp.int32))
-    return tuple(o[:nblocks] for o in outs)
+    args = [_tiles(_pad_rows(x, pad)) for x in
+            (values, gids.astype(jnp.int32), mask.astype(jnp.int32))]
+    tile = (SUBLANES, LANES)
+    return _agg_call(functools.partial(_grouped_kernel, n_groups), nblocks, group,
+                     [tile] * 3, n_groups, values.dtype, interpret, *args)
 
 
-def _fused_kernel(k, packed_ref, mask_ref,
-                  cnt_ref, s0_ref, s1_ref, mn_ref, mx_ref):
+def _fused_kernel(k, packed_ref, mask_ref, *out_refs):
     vals = _ladder(packed_ref[...], k)  # (G, 32, 128) int32, in VMEM only
-    vals = vals.reshape(vals.shape[0], PACK_BLOCK)
     gids = jnp.zeros(vals.shape, jnp.int32)
-    cnt, s0, s1, mn, mx = grouped_agg(vals, gids, mask_ref[...], 1)
-    cnt_ref[...], s0_ref[...], s1_ref[...] = cnt, s0, s1
-    mn_ref[...], mx_ref[...] = mn, mx
+    _write(out_refs, grouped_agg_tiles(vals, gids, mask_ref[...], 1))
 
 
 @functools.partial(jax.jit, static_argnames=("k", "group", "interpret"))
@@ -108,7 +120,7 @@ def fused_agg_pallas(
     mask: jax.Array,
     *,
     group: int = DEFAULT_GROUP,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """packed (nblocks, k, 128) uint32 BITPACK pages + mask (nblocks,
     4096) int32 -> 5 x (nblocks, 1) accumulators, decode fused in-kernel
@@ -116,21 +128,6 @@ def fused_agg_pallas(
     nblocks = packed.shape[0]
     group = min(group, nblocks)
     pad = (-nblocks) % group
-    if pad:
-        packed = jnp.pad(packed, ((0, pad), (0, 0), (0, 0)))
-        mask = jnp.pad(mask, ((0, pad), (0, 0)))
-    steps = packed.shape[0] // group
-    out_shape = _out_shapes(packed.shape[0], 1, jnp.int32)
-    gspec = pl.BlockSpec((group, 1), lambda i: (i, 0))
-    outs = pl.pallas_call(
-        functools.partial(_fused_kernel, k),
-        grid=(steps,),
-        in_specs=[
-            pl.BlockSpec((group, k, LANES), lambda i: (i, 0, 0)),
-            pl.BlockSpec((group, PACK_BLOCK), lambda i: (i, 0)),
-        ],
-        out_specs=[gspec] * 5,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(packed, mask.astype(jnp.int32))
-    return tuple(o[:nblocks] for o in outs)
+    args = [_pad_rows(packed, pad), _tiles(_pad_rows(mask.astype(jnp.int32), pad))]
+    return _agg_call(functools.partial(_fused_kernel, k), nblocks, group,
+                     [(k, LANES), (SUBLANES, LANES)], 1, jnp.int32, interpret, *args)

@@ -50,7 +50,7 @@ def _kernel(k: int, packed_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("k", "group", "interpret"))
 def bitunpack_pallas(
-    packed: jax.Array, k: int, *, group: int = DEFAULT_GROUP, interpret: bool = True
+    packed: jax.Array, k: int, *, group: int = DEFAULT_GROUP, interpret: bool
 ) -> jax.Array:
     """(nblocks, k, 128) uint32 -> (nblocks, 32, 128) int32."""
     nblocks = packed.shape[0]

@@ -28,13 +28,14 @@ from repro.lakeformat.encodings import LANES, PACK_BLOCK, SUBLANES
 DEFAULT_GROUP = 4
 
 
-def _lane_prefix_sum(x: jax.Array) -> jax.Array:
-    """Inclusive prefix sum along the last (lane) axis via log-shift adds."""
-    n = x.shape[-1]
+def _prefix_sum(x: jax.Array, axis: int) -> jax.Array:
+    """Inclusive prefix sum along `axis` via log-shift adds."""
+    n = x.shape[axis]
+    pads = [(0, 0)] * x.ndim
     sh = 1
     while sh < n:
-        shifted = jnp.pad(x[..., :-sh], [(0, 0)] * (x.ndim - 1) + [(sh, 0)])
-        x = x + shifted
+        pads[axis] = (sh, 0)
+        x = x + jnp.pad(jax.lax.slice_in_dim(x, 0, n - sh, axis=axis), pads)
         sh *= 2
     return x
 
@@ -46,11 +47,10 @@ def _kernel(k: int, packed_ref, bases_ref, out_ref):
         zu & jnp.uint32(1)
     ).astype(jnp.int32)
     # row totals via tree reduction — does NOT wait on the lane scan
-    row_tot = jnp.sum(d, axis=2)  # (G,32)
-    row_carry = _lane_prefix_sum(row_tot) - row_tot  # exclusive over rows
-    lane_cs = _lane_prefix_sum(d)  # the single full-width lane scan
-    out = lane_cs + row_carry[:, :, None] + bases_ref[...][:, :1, None]
-    out_ref[...] = out.reshape(out_ref.shape)
+    row_tot = jnp.sum(d, axis=2, keepdims=True)  # (G,32,1)
+    row_carry = _prefix_sum(row_tot, 1) - row_tot  # exclusive over rows
+    lane_cs = _prefix_sum(d, 2)  # the single full-width lane scan
+    out_ref[...] = lane_cs + row_carry + bases_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("k", "group", "interpret"))
@@ -60,7 +60,7 @@ def delta_decode_pallas(
     k: int,
     *,
     group: int = DEFAULT_GROUP,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """(nblocks,k,128) zigzag deltas + (nblocks,) int32 bases -> (nblocks,4096) int32."""
     nblocks = packed.shape[0]
@@ -69,17 +69,16 @@ def delta_decode_pallas(
     if pad:
         packed = jnp.pad(packed, ((0, pad), (0, 0), (0, 0)))
         bases = jnp.pad(bases, (0, pad))
-    bases2d = bases.astype(jnp.int32)[:, None]  # (nb,1) — 2D for TPU layout
-    steps = packed.shape[0] // group
+    nb = packed.shape[0]
     out = pl.pallas_call(
         functools.partial(_kernel, k),
-        grid=(steps,),
+        grid=(nb // group,),
         in_specs=[
             pl.BlockSpec((group, k, LANES), lambda i: (i, 0, 0)),
-            pl.BlockSpec((group, 1), lambda i: (i, 0)),
+            pl.BlockSpec((group, 1, 1), lambda i: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((group, PACK_BLOCK), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((packed.shape[0], PACK_BLOCK), jnp.int32),
+        out_specs=pl.BlockSpec((group, SUBLANES, LANES), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((nb, SUBLANES, LANES), jnp.int32),
         interpret=interpret,
-    )(packed, bases2d)
-    return out[:nblocks]
+    )(packed, bases.astype(jnp.int32).reshape(nb, 1, 1))
+    return out.reshape(nb, PACK_BLOCK)[:nblocks]

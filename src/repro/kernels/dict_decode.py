@@ -5,17 +5,10 @@ with the shared shift ladder (bitunpack.py) and immediately looked up in a
 VMEM-resident dictionary, so codes never round-trip to HBM — the fusion the
 paper's SmartNIC gets for free by being a pipeline.
 
-Lookup strategies, chosen statically:
-  - small code widths (k <= SELECT_MAX_K): gather-free arithmetic select —
-    a flat mux over the 2^k possible codes (`jnp.where(code == i, d[i], …)`
-    chained).  Pure lane compares + selects on the VPU, no gather and no
-    one-hot matmul; each code matches exactly one arm, so the result is
-    bit-identical to `jnp.take(..., mode="clip")` for ints AND floats.
-    Low-cardinality dictionaries are the common case the paper's workloads
-    lean on (countries, flags, status enums), so this is the hot path.
-  - larger dicts, float values (<= ONE_HOT_MAX entries): one-hot matmul on
-    the MXU (gather-free, always lowers on TPU).
-  - otherwise: vector gather (jnp.take) against the VMEM dictionary.
+The dictionary is held as (R, 128) rows and read with `lookup.lane_lookup`
+(one lane gather per row), which is pure selection: bit-identical to
+`jnp.take(..., mode="clip")` for ints and floats alike.  Codes are clipped
+to the TRUE dictionary length first, exactly as the reference clips.
 """
 
 from __future__ import annotations
@@ -27,70 +20,36 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.bitunpack import _ladder
+from repro.kernels.lookup import as_rows, lane_lookup
 from repro.lakeformat.encodings import LANES, SUBLANES
 
-ONE_HOT_MAX = 256
-SELECT_MAX_K = 4  # <= 16 dictionary entries: flat select mux beats a gather
 DEFAULT_GROUP = 4
 
 
-def _select_shared(codes: jax.Array, d: jax.Array, n: int) -> jax.Array:
-    """Flat mux of one shared dictionary: out[...] = d[codes[...]] for
-    codes < n, exact for any dtype (selection, never arithmetic)."""
-    out = jnp.full(codes.shape, d[0], dtype=d.dtype)
-    for i in range(1, n):
-        out = jnp.where(codes == i, d[i], out)
-    return out
-
-
-def _select_per_block(codes: jax.Array, d: jax.Array, n: int) -> jax.Array:
-    """Flat mux with a per-block dictionary row: codes (G,32,128) int32,
-    d (G, Dpad); out[g, ...] = d[g, codes[g, ...]] for codes < n."""
-    out = jnp.broadcast_to(d[:, 0][:, None, None], codes.shape).astype(d.dtype)
-    for i in range(1, n):
-        out = jnp.where(codes == i, d[:, i][:, None, None], out)
-    return out
-
-
-def _kernel(k: int, mode: str, n_true: int, packed_ref, dict_ref, out_ref):
-    # clip against the TRUE dictionary length, not the lane-padded one:
-    # ref.dict_decode clips out-of-dict codes to the last real entry, and
-    # reading a pad slot instead would break bit-identity
+def _kernel(k: int, n_true: int, packed_ref, dict_ref, out_ref):
     codes = jnp.clip(_ladder(packed_ref[...], k), 0, n_true - 1)
-    d = dict_ref[...]  # (Dpad,)
-    if mode == "select":
-        # clipped codes < min(2^k, n_true), so that many mux arms cover
-        # every reachable code
-        out_ref[...] = _select_shared(
-            codes, d, min(1 << k, n_true)
-        ).astype(out_ref.dtype)
-    elif mode == "one_hot":
-        G = codes.shape[0]
-        flat = codes.reshape(G * SUBLANES, LANES)  # (rows, 128)
-        oh = (flat[:, :, None] == jnp.arange(d.shape[0], dtype=jnp.int32)[None, None, :])
-        vals = jnp.einsum(
-            "rlD,D->rl", oh.astype(jnp.float32), d.astype(jnp.float32),
-            preferred_element_type=jnp.float32,
-        )
-        out_ref[...] = vals.reshape(codes.shape).astype(out_ref.dtype)
-    else:
-        out_ref[...] = jnp.take(d, codes, axis=0, mode="clip").astype(out_ref.dtype)
+    d = dict_ref[...]  # (R, 128)
+    for g in range(codes.shape[0]):
+        out_ref[g] = lane_lookup(d, codes[g]).astype(out_ref.dtype)
 
 
-def _batch_kernel(k: int, select: bool, packed_ref, dict_ref, size_ref, out_ref):
+def _batch_kernel(k: int, packed_ref, dict_ref, size_ref, out_ref):
     """Per-BLOCK dictionaries: each block of codes looks up its own
-    dictionary row (pre-gathered to (G, Dpad) by the ops wrapper), clipped
+    dictionary rows (pre-gathered per block by the ops wrapper), clipped
     to its own dictionary's true length — exactly `jnp.take(dict_p, codes,
     mode="clip")` per source page, so batched == sequential bit-for-bit."""
     codes = _ladder(packed_ref[...], k)  # (G, 32, 128) int32
-    d = dict_ref[...]  # (G, Dpad)
-    lim = (size_ref[...] - 1).astype(jnp.int32)  # (G, 1)
-    c = jnp.clip(codes, 0, lim[:, :, None])  # (G, 32, 128)
-    if select:
-        out_ref[...] = _select_per_block(c, d, 1 << k).astype(out_ref.dtype)
-    else:
-        flat = jnp.take_along_axis(d, c.reshape(c.shape[0], -1), axis=1)
-        out_ref[...] = flat.reshape(codes.shape).astype(out_ref.dtype)
+    for g in range(codes.shape[0]):
+        lim = size_ref[g] - 1  # (1, 1)
+        c = jnp.clip(codes[g], 0, lim)
+        out_ref[g] = lane_lookup(dict_ref[g], c).astype(out_ref.dtype)
+
+
+def _pad_group(x, group: int, fill=0):
+    pad = (-x.shape[0]) % group
+    if not pad:
+        return x
+    return jnp.pad(x, [(0, pad)] + [(0, 0)] * (x.ndim - 1), constant_values=fill)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "group", "interpret"))
@@ -101,7 +60,7 @@ def dict_decode_batch_pallas(
     k: int,
     *,
     group: int = DEFAULT_GROUP,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Batched multi-page dict decode in ONE kernel launch.
 
@@ -113,25 +72,22 @@ def dict_decode_batch_pallas(
     """
     nblocks = packed.shape[0]
     group = min(group, nblocks)
-    pad = (-nblocks) % group
-    if pad:
-        packed = jnp.pad(packed, ((0, pad), (0, 0), (0, 0)))
-        dicts = jnp.pad(dicts, ((0, pad), (0, 0)))
-        sizes = jnp.pad(sizes, ((0, pad), (0, 0)), constant_values=1)
+    packed = _pad_group(packed, group)
+    dicts = _pad_group(dicts, group)
+    sizes = _pad_group(sizes, group, fill=1)
     dpad = (-dicts.shape[1]) % LANES
     if dpad:
         dicts = jnp.pad(dicts, ((0, 0), (0, dpad)))
-    # clipped codes are < sizes <= 2^k <= Dpad for k <= SELECT_MAX_K, so the
-    # mux arms cover every reachable code
-    select = k <= SELECT_MAX_K and (1 << k) <= dicts.shape[1]
-    steps = packed.shape[0] // group
+    rows = dicts.shape[1] // LANES
+    dicts = dicts.reshape(dicts.shape[0], rows, LANES)
+    sizes = sizes.astype(jnp.int32).reshape(-1, 1, 1)
     out = pl.pallas_call(
-        functools.partial(_batch_kernel, k, select),
-        grid=(steps,),
+        functools.partial(_batch_kernel, k),
+        grid=(packed.shape[0] // group,),
         in_specs=[
             pl.BlockSpec((group, k, LANES), lambda i: (i, 0, 0)),
-            pl.BlockSpec((group, dicts.shape[1]), lambda i: (i, 0)),
-            pl.BlockSpec((group, 1), lambda i: (i, 0)),
+            pl.BlockSpec((group, rows, LANES), lambda i: (i, 0, 0)),
+            pl.BlockSpec((group, 1, 1), lambda i: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((group, SUBLANES, LANES), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(
@@ -149,39 +105,25 @@ def dict_decode_pallas(
     k: int,
     *,
     group: int = DEFAULT_GROUP,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """(nblocks,k,128) uint32 codes + (D,) dict -> (nblocks,32,128) values."""
     nblocks = packed.shape[0]
     group = min(group, nblocks)
-    pad = (-nblocks) % group
-    if pad:
-        packed = jnp.pad(packed, ((0, pad), (0, 0), (0, 0)))
+    packed = _pad_group(packed, group)
     n_true = dictionary.shape[0]
-    dpad = (-dictionary.shape[0]) % LANES
-    if dpad:
-        dictionary = jnp.pad(dictionary, (0, dpad))
-    if k <= SELECT_MAX_K:
-        mode = "select"  # exact for ints and floats alike
-    elif dictionary.shape[0] <= ONE_HOT_MAX and jnp.issubdtype(
-        dictionary.dtype, jnp.floating
-    ):
-        # One-hot path is exact only for f32-representable values
-        mode = "one_hot"
-    else:
-        mode = "gather"
-    steps = packed.shape[0] // group
+    d = as_rows(dictionary)
     out = pl.pallas_call(
-        functools.partial(_kernel, k, mode, n_true),
-        grid=(steps,),
+        functools.partial(_kernel, k, n_true),
+        grid=(packed.shape[0] // group,),
         in_specs=[
             pl.BlockSpec((group, k, LANES), lambda i: (i, 0, 0)),
-            pl.BlockSpec((dictionary.shape[0],), lambda i: (0,)),
+            pl.BlockSpec(d.shape, lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((group, SUBLANES, LANES), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(
             (packed.shape[0], SUBLANES, LANES), dictionary.dtype
         ),
         interpret=interpret,
-    )(packed, dictionary)
+    )(packed, d)
     return out[:nblocks]

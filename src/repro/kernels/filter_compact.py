@@ -33,43 +33,39 @@ def _prefix_sum_last(x: jax.Array) -> jax.Array:
 
 
 def _kernel(vals_ref, mask_ref, out_ref, cnt_ref):
-    vals = vals_ref[...]  # (1, B)
-    m = mask_ref[...].astype(jnp.int32)  # (1, B)
+    vals = vals_ref[0]  # (1, B)
+    m = mask_ref[0]  # (1, B) int32
     pos = _prefix_sum_last(m) - 1  # (1, B)
-    slots = jax.lax.broadcasted_iota(jnp.int32, (1, BLOCK, 1), 1)  # (1, p, 1)
-    onehot = (pos[:, None, :] == slots) & (m[:, None, :] > 0)  # (1, p, j)
+    slots = jax.lax.broadcasted_iota(jnp.int32, (BLOCK, 1), 0)  # (p, 1)
+    onehot = (pos == slots) & (m > 0)  # (p, j)
+    # HIGHEST: every output is one value times 1.0, exact only when the
+    # MXU keeps all 24 mantissa bits (one bf16 pass keeps 8)
     out = jax.lax.dot_general(
+        vals.astype(jnp.float32),
         onehot.astype(jnp.float32),
-        vals[:, :, None].astype(jnp.float32),
-        (((2,), (1,)), ((0,), (0,))),
+        (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
-    )[..., 0]
-    out_ref[...] = out.astype(out_ref.dtype)
-    cnt_ref[...] = jnp.sum(m, axis=-1, keepdims=True)
+    )  # (1, p)
+    out_ref[0] = out.astype(out_ref.dtype)
+    cnt_ref[0] = jnp.sum(m, axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def filter_compact_pallas(
-    values: jax.Array, mask: jax.Array, *, interpret: bool = True
-):
+def filter_compact_pallas(values: jax.Array, mask: jax.Array, *, interpret: bool):
     """values (nblk, 1024), mask (nblk, 1024) int32/bool ->
     (compacted (nblk, 1024), counts (nblk,))."""
     nblk = values.shape[0]
+    spec = pl.BlockSpec((1, 1, BLOCK), lambda i: (i, 0, 0))
     out, cnt = pl.pallas_call(
         _kernel,
         grid=(nblk,),
-        in_specs=[
-            pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
-            pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ],
+        in_specs=[spec, spec],
+        out_specs=[spec, pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0))],
         out_shape=[
-            jax.ShapeDtypeStruct((nblk, BLOCK), values.dtype),
-            jax.ShapeDtypeStruct((nblk, 1), jnp.int32),
+            jax.ShapeDtypeStruct((nblk, 1, BLOCK), values.dtype),
+            jax.ShapeDtypeStruct((nblk, 1, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(values, mask.astype(jnp.int32))
-    return out, cnt[:, 0]
+    )(values.reshape(nblk, 1, BLOCK), mask.astype(jnp.int32).reshape(nblk, 1, BLOCK))
+    return out.reshape(nblk, BLOCK), cnt[:, 0, 0]

@@ -89,7 +89,7 @@ def flash_attention_pallas(
     scale: Optional[float] = None,
     bq: int = DEFAULT_BQ,
     bk: int = DEFAULT_BK,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """q (B,H,Sq,D), k/v (B,Hkv,Sk,D) -> (B,H,Sq,D).  Sq % bq == Sk % bk == 0."""
     B, H, Sq, D = q.shape

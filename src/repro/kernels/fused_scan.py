@@ -20,28 +20,30 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.bitunpack import _ladder
+from repro.kernels.lookup import as_rows, lane_lookup
+from repro.kernels.ref import tile_reduce
 from repro.lakeformat.encodings import LANES, PACK_BLOCK, SUBLANES
 
 DEFAULT_GROUP = 4
 
 
-def _kernel(k: int, has_dict: bool, *refs):
-    if has_dict:
+def _kernel(k: int, n_dict: int, *refs):
+    if n_dict:
         packed_ref, dict_ref, lohi_ref, mask_ref, cnt_ref = refs
     else:
         packed_ref, lohi_ref, mask_ref, cnt_ref = refs
     codes = _ladder(packed_ref[...], k)  # (G,32,128) int32
-    if has_dict:
-        vals = jnp.take(dict_ref[...], codes, axis=0, mode="clip")
+    if n_dict:
+        codes = jnp.clip(codes, 0, n_dict - 1)
+        d = dict_ref[...]
+        vals = jnp.stack([lane_lookup(d, codes[g]) for g in range(codes.shape[0])])
     else:
         vals = codes
-    G = vals.shape[0]
-    vals = vals.reshape(G, PACK_BLOCK)
-    lo = lohi_ref[0, 0]
-    hi = lohi_ref[0, 1]
-    m = (vals >= lo.astype(vals.dtype)) & (vals <= hi.astype(vals.dtype))
-    mask_ref[...] = m.astype(jnp.int32)
-    cnt_ref[...] = jnp.sum(m.astype(jnp.int32), axis=-1, keepdims=True)
+    lo = lohi_ref[:, 0:1].astype(vals.dtype)  # (1, 1)
+    hi = lohi_ref[:, 1:2].astype(vals.dtype)
+    m = ((vals >= lo) & (vals <= hi)).astype(jnp.int32)
+    mask_ref[...] = m
+    cnt_ref[...] = tile_reduce(jnp.sum, m)
 
 
 def _batch_kernel(k: int, packed_ref, lohi_ref, mask_ref):
@@ -49,12 +51,9 @@ def _batch_kernel(k: int, packed_ref, lohi_ref, mask_ref):
     form of the scalar kernel, so pages from many row groups (each with its
     own code-rewritten range, e.g. per-group DICT bounds) share one launch."""
     codes = _ladder(packed_ref[...], k)  # (G, 32, 128) int32
-    G = codes.shape[0]
-    vals = codes.reshape(G, PACK_BLOCK)
-    lo = lohi_ref[:, 0:1]  # (G, 1)
-    hi = lohi_ref[:, 1:2]
-    m = (vals >= lo) & (vals <= hi)
-    mask_ref[...] = m.astype(jnp.int32)
+    lo = lohi_ref[:, :, 0:1]  # (G, 1, 1)
+    hi = lohi_ref[:, :, 1:2]
+    mask_ref[...] = ((codes >= lo) & (codes <= hi)).astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "group", "interpret"))
@@ -64,7 +63,7 @@ def fused_scan_batch_pallas(
     lohi: jax.Array,
     *,
     group: int = DEFAULT_GROUP,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Batched fused decode+filter over stacked pages in ONE launch.
 
@@ -80,19 +79,19 @@ def fused_scan_batch_pallas(
         lohi = jnp.concatenate(
             [lohi, jnp.tile(jnp.array([[1, 0]], jnp.int32), (pad, 1))], axis=0
         )
-    steps = packed.shape[0] // group
+    nb = packed.shape[0]
     mask = pl.pallas_call(
         functools.partial(_batch_kernel, k),
-        grid=(steps,),
+        grid=(nb // group,),
         in_specs=[
             pl.BlockSpec((group, k, LANES), lambda i: (i, 0, 0)),
-            pl.BlockSpec((group, 2), lambda i: (i, 0)),
+            pl.BlockSpec((group, 1, 2), lambda i: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((group, PACK_BLOCK), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((packed.shape[0], PACK_BLOCK), jnp.int32),
+        out_specs=pl.BlockSpec((group, SUBLANES, LANES), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((nb, SUBLANES, LANES), jnp.int32),
         interpret=interpret,
-    )(packed, lohi)
-    return mask[:nblocks]
+    )(packed, lohi.astype(jnp.int32).reshape(nb, 1, 2))
+    return mask.reshape(nb, PACK_BLOCK)[:nblocks]
 
 
 @functools.partial(jax.jit, static_argnames=("k", "group", "interpret"))
@@ -104,7 +103,7 @@ def fused_scan_pallas(
     dictionary: Optional[jax.Array] = None,
     *,
     group: int = DEFAULT_GROUP,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Returns (mask (nblocks, 4096) int32, counts (nblocks,) int32)."""
     nblocks = packed.shape[0]
@@ -112,30 +111,30 @@ def fused_scan_pallas(
     pad = (-nblocks) % group
     if pad:
         packed = jnp.pad(packed, ((0, pad), (0, 0), (0, 0)))
-    steps = packed.shape[0] // group
+    nb = packed.shape[0]
     lohi = jnp.stack([lo, hi]).astype(jnp.int32)[None, :]  # (1, 2)
     in_specs = [pl.BlockSpec((group, k, LANES), lambda i: (i, 0, 0))]
     args = [packed]
+    n_dict = 0
     if dictionary is not None:
-        dpad = (-dictionary.shape[0]) % LANES
-        if dpad:
-            dictionary = jnp.pad(dictionary, (0, dpad))
-        in_specs.append(pl.BlockSpec((dictionary.shape[0],), lambda i: (0,)))
-        args.append(dictionary.astype(jnp.int32))
+        n_dict = dictionary.shape[0]
+        d = as_rows(dictionary)
+        in_specs.append(pl.BlockSpec(d.shape, lambda i: (0, 0)))
+        args.append(d)
     in_specs.append(pl.BlockSpec((1, 2), lambda i: (0, 0)))
     args.append(lohi)
     mask, cnt = pl.pallas_call(
-        functools.partial(_kernel, k, dictionary is not None),
-        grid=(steps,),
+        functools.partial(_kernel, k, n_dict),
+        grid=(nb // group,),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((group, PACK_BLOCK), lambda i: (i, 0)),
-            pl.BlockSpec((group, 1), lambda i: (i, 0)),
+            pl.BlockSpec((group, SUBLANES, LANES), lambda i: (i, 0, 0)),
+            pl.BlockSpec((group, 1, 1), lambda i: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((packed.shape[0], PACK_BLOCK), jnp.int32),
-            jax.ShapeDtypeStruct((packed.shape[0], 1), jnp.int32),
+            jax.ShapeDtypeStruct((nb, SUBLANES, LANES), jnp.int32),
+            jax.ShapeDtypeStruct((nb, 1, 1), jnp.int32),
         ],
         interpret=interpret,
     )(*args)
-    return mask[:nblocks], cnt[:nblocks, 0]
+    return mask.reshape(nb, PACK_BLOCK)[:nblocks], cnt[:nblocks, 0, 0]

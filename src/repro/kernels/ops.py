@@ -1,10 +1,13 @@
 """Public jit'd kernel API: dispatches Pallas kernel vs pure-jnp reference.
 
 backend:
-  'ref'    — pure jnp (default on CPU; also the dry-run path, since Pallas
-             TPU lowering is unavailable on the CPU dry-run backend)
-  'pallas' — pl.pallas_call (interpret=True automatically off-TPU)
-  'auto'   — 'pallas' on TPU, 'ref' elsewhere
+  'ref'    — pure jnp (what 'auto' picks off a TPU; also the dry-run path,
+             since Pallas TPU lowering is unavailable on the CPU backend)
+  'pallas' — pl.pallas_call, compiled on a TPU and interpreted elsewhere
+  'auto'   — 'pallas' on TPU, 'ref' elsewhere (the default everywhere)
+
+`_resolve` is the one place that decides interpret mode: the kernel
+signatures take `interpret` without a default.
 
 Every function here is shape/dtype-stable across backends; tests assert
 exact agreement.

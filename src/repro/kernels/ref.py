@@ -131,13 +131,12 @@ def filter_compact(values: jax.Array, mask: jax.Array) -> Tuple[jax.Array, jax.A
     pos = jnp.cumsum(m, axis=1) - 1  # target slot per survivor
     slots = jnp.arange(B, dtype=jnp.int32)[None, :, None]  # (1,B,1) target p
     onehot = ((pos[:, None, :] == slots) & mask[:, None, :])  # (nblk, p, j)
-    if jnp.issubdtype(values.dtype, jnp.floating):
-        out = jnp.einsum("bpj,bj->bp", onehot.astype(jnp.float32), values.astype(jnp.float32))
-        out = out.astype(values.dtype)
-    else:
-        out = jnp.einsum(
-            "bpj,bj->bp", onehot.astype(jnp.float32), values.astype(jnp.float32)
-        ).astype(values.dtype)
+    # HIGHEST: on a TPU the default f32 matmul is one bf16 pass, which
+    # would round every compacted value to 8 mantissa bits
+    out = jnp.einsum(
+        "bpj,bj->bp", onehot.astype(jnp.float32), values.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    ).astype(values.dtype)
     return out, jnp.sum(m, axis=1)
 
 
@@ -220,13 +219,62 @@ AGG_INT_MASK = 0xFFFF
 
 # identity fills for (block, group) cells with no masked member.  Plain
 # Python scalars on purpose: jnp constants would be captured by the
-# pallas kernel bodies that call grouped_agg, which pallas_call rejects.
+# pallas kernel bodies that call grouped_agg_tiles, which pallas_call rejects.
 AGG_INT_MIN_IDENT = 2**31 - 1
 AGG_INT_MAX_IDENT = -(2**31)
 AGG_FLT_MIN_IDENT = float("inf")
 AGG_FLT_MAX_IDENT = float("-inf")
 
 
+def tile_reduce(fn, x: jax.Array) -> jax.Array:
+    """(nblk, R, 128) -> (nblk, 1, 1), lanes first then sublanes (Mosaic
+    reduces one axis at a time).  Shared with the Pallas kernels, so their
+    interpret-mode float sums agree with this oracle bit-for-bit."""
+    return fn(fn(x, axis=2, keepdims=True), axis=1, keepdims=True)
+
+
+def grouped_agg_tiles(
+    values: jax.Array, gids: jax.Array, mask: jax.Array, n_groups: int
+) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
+    """`grouped_agg` on blocks in tile layout: (nblk, R, 128) inputs ->
+    five (nblk, 1, n_groups) planes.  A loop over groups: one masked
+    reduction per group, placed into its lane by a select; this body is also the
+    grouped_agg / fused_agg Pallas kernels' body."""
+    shape = (values.shape[0], 1, n_groups)
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 2)
+    live = mask.astype(jnp.int32) != 0
+    gids = gids.astype(jnp.int32)
+    is_float = jnp.issubdtype(values.dtype, jnp.floating)
+    if is_float:
+        v = values.astype(jnp.float32)
+        lo_ident, hi_ident = AGG_FLT_MIN_IDENT, AGG_FLT_MAX_IDENT
+    else:
+        v = values.astype(jnp.int32)
+        vhi, vlo = v >> AGG_INT_SHIFT, v & AGG_INT_MASK
+        lo_ident, hi_ident = AGG_INT_MIN_IDENT, AGG_INT_MAX_IDENT
+
+    def one_group(g, planes):
+        cnt, s0, s1, mn, mx = planes
+        m = live & (gids == g)
+        at = lane == g
+        cnt = jnp.where(at, tile_reduce(jnp.sum, m.astype(jnp.int32)), cnt)
+        if is_float:
+            s0 = jnp.where(at, tile_reduce(jnp.sum, jnp.where(m, v, 0.0)), s0)
+        else:
+            s0 = jnp.where(at, tile_reduce(jnp.sum, jnp.where(m, vhi, 0)), s0)
+            s1 = jnp.where(at, tile_reduce(jnp.sum, jnp.where(m, vlo, 0)), s1)
+        mn = jnp.where(at, tile_reduce(jnp.min, jnp.where(m, v, lo_ident)), mn)
+        mx = jnp.where(at, tile_reduce(jnp.max, jnp.where(m, v, hi_ident)), mx)
+        return cnt, s0, s1, mn, mx
+
+    zeros = jnp.zeros(shape, jnp.int32)
+    init = (zeros, jnp.zeros(shape, v.dtype), zeros,
+            jnp.full(shape, lo_ident, v.dtype), jnp.full(shape, hi_ident, v.dtype))
+    cnt, s0, s1, mn, mx = jax.lax.fori_loop(0, n_groups, one_group, init)
+    return cnt, s0, s1, mn.astype(values.dtype), mx.astype(values.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("n_groups",))
 def grouped_agg(
     values: jax.Array, gids: jax.Array, mask: jax.Array, n_groups: int
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
@@ -240,28 +288,18 @@ def grouped_agg(
       mn   value dtype, min (identity fill where the cell is empty)
       mx   value dtype, max (identity fill where the cell is empty)
 
-    All reductions are WITHIN a block (axis 1), so computing any subset of
+    All reductions are WITHIN a block, so computing any subset of
     blocks yields bit-identical rows — the pallas kernel's grid steps and
     this oracle agree exactly, and cross-block merging happens host-side
-    in int64/float64 (core/agg.py)."""
-    oh = (
-        gids.astype(jnp.int32)[:, :, None]
-        == jnp.arange(n_groups, dtype=jnp.int32)[None, None, :]
-    ) & (mask.astype(jnp.int32) != 0)[:, :, None]
-    cnt = jnp.sum(oh.astype(jnp.int32), axis=1)
-    v = values[:, :, None]
-    if jnp.issubdtype(values.dtype, jnp.floating):
-        s0 = jnp.sum(jnp.where(oh, v.astype(jnp.float32), 0.0), axis=1)
-        s1 = jnp.zeros_like(cnt)
-        mn = jnp.min(jnp.where(oh, v, AGG_FLT_MIN_IDENT), axis=1)
-        mx = jnp.max(jnp.where(oh, v, AGG_FLT_MAX_IDENT), axis=1)
-    else:
-        vi = values.astype(jnp.int32)[:, :, None]
-        s0 = jnp.sum(jnp.where(oh, vi >> AGG_INT_SHIFT, 0), axis=1)
-        s1 = jnp.sum(jnp.where(oh, vi & AGG_INT_MASK, 0), axis=1)
-        mn = jnp.min(jnp.where(oh, vi, AGG_INT_MIN_IDENT), axis=1)
-        mx = jnp.max(jnp.where(oh, vi, AGG_INT_MAX_IDENT), axis=1)
-    return cnt, s0, s1, mn.astype(values.dtype), mx.astype(values.dtype)
+    in int64/float64 (core/agg.py).  B is a multiple of 128: each block
+    is reduced in its (B // 128, 128) tile layout."""
+    nblk, B = values.shape
+
+    def tiles(x):
+        return x.reshape(nblk, B // LANES, LANES)
+
+    planes = grouped_agg_tiles(tiles(values), tiles(gids), tiles(mask), n_groups)
+    return tuple(p.reshape(nblk, n_groups) for p in planes)
 
 
 def fused_agg_scan(

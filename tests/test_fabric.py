@@ -344,6 +344,23 @@ def test_cross_request_stacking_bit_identical_and_fewer_launches(readers):
             < seq.telemetry.counters["decode_launches"])
 
 
+def test_cross_request_pass_raises_errors_no_request_caused(readers, monkeypatch):
+    """Only one request's own fault reruns the group per request; an error
+    of the launch itself (a kernel that fails to lower, a device fault)
+    raises out of the tick instead of hiding behind the fallback."""
+    pod = _stacking_pod()
+
+    def broken(*args, **kwargs):
+        raise RuntimeError("kernel failed to lower")
+
+    monkeypatch.setattr(pod.engine, "scan_group_batched", broken)
+    for tenant, plan in zip("ab", PLANS[:2]):
+        pod.submit(tenant, readers["lineitem"], plan)
+    with pytest.raises(RuntimeError, match="failed to lower"):
+        pod.drain()
+    assert pod.telemetry.counters.get("xreq_fallback", 0) == 0
+
+
 def test_fabric_stacks_across_requests_and_stays_identical(readers):
     fab = ScanFabric(n_pods=2, policy=StaticPolicy("raw"))
     r = readers["lineitem"]

@@ -6,7 +6,7 @@ invariants that make ladder bucketing strictly no worse than pow2.
 
 Bit-identity is the contract: the RLE rank lookup gathers the single
 owning run, the DELTA carry ladder reassociates int32 adds (associative
-mod 2^32), and the DICT select mux is pure selection — so every compare
+mod 2^32), and the DICT lane lookup is pure selection — so every compare
 here is array_equal, never allclose.
 """
 
@@ -132,8 +132,8 @@ def test_delta_parity_across_k_and_ladder_boundaries():
 
 @pytest.mark.parametrize("float_vals", [False, True], ids=["int32", "float32"])
 def test_dict_parity_across_k_and_ladder_boundaries(float_vals):
-    # k sweeps the full code width range incl. the select-mux regime
-    # (k <= SELECT_MAX_K), one-hot, and gather fallbacks
+    # k sweeps the full code width range: dictionaries of one 128-entry
+    # lookup row up to 32 rows
     rng = np.random.default_rng(3 if float_vals else 4)
     for i, k in enumerate(range(1, 33)):
         nb = LADDER_NS[i % len(LADDER_NS)]
@@ -142,13 +142,12 @@ def test_dict_parity_across_k_and_ladder_boundaries(float_vals):
 
 
 def test_dict_single_call_select_mux_matches_oracle():
-    """The arithmetic-select path (k <= SELECT_MAX_K) vs the take oracle,
-    int and float dictionaries, including clip semantics for codes that
-    are representable in k bits but >= the true dict size."""
-    from repro.kernels.dict_decode import SELECT_MAX_K
-
+    """The single-call lookup (one lane gather per 128-entry dictionary
+    row, one row up to 32 rows) vs the take oracle, int and float
+    dictionaries, including clip semantics for codes that are
+    representable in k bits but >= the true dict size."""
     rng = np.random.default_rng(5)
-    for k in range(1, SELECT_MAX_K + 1):
+    for k in range(1, 13):
         for float_vals in (False, True):
             D = int(rng.integers(1, (1 << k) + 1))
             # codes deliberately cover the full k-bit range: codes >= D
