@@ -89,7 +89,6 @@ from repro.core import BlockCache, DatapathEngine, tpch
 from repro.core.plan import Cmp, ScanPlan
 from repro.core.queries import QUERIES, run_via_service
 from repro.datapath import (
-    PAPER_FIG2_PCT,
     AdaptiveOffloadPolicy,
     CostModel,
     DatapathService,
@@ -436,7 +435,7 @@ def run_trace(sf: float = 0.1) -> dict:
     overhead = wall_on / max(wall_off, 1e-9)
 
     rep = svc_on.telemetry.trace_report()
-    pct = rep["stage_pct"]
+    st = rep["stage_s"]
     chrome_events = len(svc_on.tracer.recorder.to_chrome_trace()["traceEvents"])
     row("service.trace.overhead", wall_on,
         f"wall_off_s={wall_off:.3f};ratio={overhead:.3f}x;"
@@ -444,10 +443,8 @@ def run_trace(sf: float = 0.1) -> dict:
         f"recorded={rep['recorded']}/{rep['completed']};"
         f"chrome_events={chrome_events}")
     row("service.trace.stages", 0.0,
-        f"decode={pct['decode']:.1f}%;filter={pct['filter']:.1f}%;"
-        f"rest={pct['rest']:.1f}%"
-        f" (paper fig2: decode={PAPER_FIG2_PCT['decode']:.0f}%"
-        f"/filter={PAPER_FIG2_PCT['filter']:.0f}%)")
+        f"host seconds: decode={st['decode']:.4f};filter={st['filter']:.4f};"
+        f"fetch={st['fetch']:.4f};wall={rep['wall_s']:.4f}")
     return {
         "wall_traced_s": wall_on,
         "wall_untraced_s": wall_off,
@@ -456,11 +453,7 @@ def run_trace(sf: float = 0.1) -> dict:
         "recorded": rep["recorded"],
         "completed": rep["completed"],
         "chrome_events": chrome_events,
-        "decode_pct": pct["decode"],
-        "filter_pct": pct["filter"],
-        "rest_pct": pct["rest"],
         "stage_s": rep["stage_s"],
-        "paper_fig2_pct": dict(sorted(PAPER_FIG2_PCT.items())),
     }
 
 

@@ -155,21 +155,18 @@ def main():
           f" (ring capacity {trep['capacity']})")
     print(f"  timeline export        : {trace_path} ({n_events} events —"
           f" load in ui.perfetto.dev)")
-    print("  stage attribution (% of request wall):")
-    print(f"    {'tenant':10s} {'n':>3s} {'decode':>8s} {'filter':>8s}"
-          f" {'fetch':>8s} {'wait':>8s} {'rest':>8s}")
-    for t, bt in trep["by_tenant"].items():
-        waits = bt["stage_pct"]["wfq_wait"] + bt["stage_pct"]["hold_window"]
-        print(f"    {t:10s} {bt['n']:3d} {bt['decode_pct']:7.1f}%"
-              f" {bt['filter_pct']:7.1f}% {bt['stage_pct']['fetch']:7.1f}%"
-              f" {waits:7.1f}% {bt['rest_pct']:7.1f}%")
-    fleet = trep["stage_pct"]
-    anchor = trep["paper_fig2_pct"]
-    print(f"    {'fleet':10s} {trep['recorded']:3d} {fleet['decode']:7.1f}%"
-          f" {fleet['filter']:7.1f}%     ---      ---  {fleet['rest']:7.1f}%")
-    print(f"  paper Fig. 2 anchor    : decode={anchor['decode']:.0f}%"
-          f" filter={anchor['filter']:.0f}% rest={anchor['rest']:.0f}%"
-          f"  (TPC-H on Parquet)")
+    print("  stage attribution (host seconds; launches are asynchronous, so")
+    print("  a stage holds its host work and any device wait that falls in it):")
+    print(f"    {'tenant':10s} {'n':>3s} {'wall':>8s} {'decode':>8s} {'filter':>8s}"
+          f" {'fetch':>8s} {'wait':>8s}")
+    rows = list(trep["by_tenant"].items()) + [
+        ("fleet", {"n": trep["recorded"], "wall_s": trep["wall_s"],
+                   "stage_s": trep["stage_s"]})]
+    for t, bt in rows:
+        st = bt["stage_s"]
+        print(f"    {t:10s} {bt['n']:3d} {bt['wall_s']:8.4f} {st['decode']:8.4f}"
+              f" {st['filter']:8.4f} {st['fetch']:8.4f}"
+              f" {st['wfq_wait'] + st['hold_window']:8.4f}")
 
     snap = svc.telemetry.snapshot()
     c = snap["counters"]

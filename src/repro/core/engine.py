@@ -38,6 +38,7 @@ device dispatches.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import hashlib
 from typing import Dict, List, Optional
@@ -72,20 +73,21 @@ from repro.lakeformat.encodings import (
 )
 from repro.lakeformat.integrity import CorruptPageError, page_checksum
 
-# Flight-recorder hook: the repro.datapath.trace MODULE, installed by the
-# datapath scheduler at its import time (engine cannot import datapath —
-# that would close an import cycle through the package __init__).  None
-# for library users who never touch the service, so direct scans pay one
-# module-attribute load and nothing else.
+# Span hook: the repro.datapath.trace MODULE, installed by the datapath
+# scheduler at its import time (engine cannot import datapath — that
+# would close an import cycle through the package __init__).  None for
+# library users who never touch the service.
 TRACE = None
+_NO_SPAN = contextlib.nullcontext()
 
 
-def _tr():
-    """The trace module iff a traced service slice is executing right
-    now, else None.  Call sites gate EVERY span kwarg construction on
-    this, which is what keeps the untraced hot path allocation-free."""
+def _span(name: str):
+    """`trace.span(name)` once the datapath has installed its trace
+    module, else a null context.  Its `as` value is None when nothing
+    records, so call sites build their counts only under
+    `if sp is not None`."""
     t = TRACE
-    return t if t is not None and t._CUR is not None else None
+    return _NO_SPAN if t is None else t.span(name)
 
 
 @dataclasses.dataclass
@@ -328,13 +330,12 @@ class DatapathEngine:
         if precomputed is not None:
             arr = precomputed  # bucket launch already counted by the caller
         else:
-            tr = _tr()
-            if tr is not None:
-                tr.begin("decode_launch", rg=rg, column=name,
-                         encoding=col.encoding.value, rows=L)
-            arr = self._decode_host(col, L) if self.backend == "host" else self._decode_device(col, L)
-            if tr is not None:
-                tr.end(name="decode_launch", nbytes=int(arr.nbytes))
+            with _span("engine.decode") as sp:
+                arr = (self._decode_host(col, L) if self.backend == "host"
+                       else self._decode_device(col, L))
+                if sp is not None:
+                    sp.set(rg=rg, column=name, encoding=col.encoding.value,
+                           pages=1, rows=L, nbytes=int(arr.nbytes))
             if stats is not None:
                 stats.kernel_launches += 1
         enc_name = col.encoding.value if col is not None else None
@@ -417,18 +418,16 @@ class DatapathEngine:
 
     def _eval_mask(self, pred: Optional[Expr], cols, blooms, L: int, rg: int,
                    bmasks: Optional[Dict] = None):
-        """Predicate eval wrapped in a `filter` span (no predicate: an
-        all-true validity mask, not filter work, so no span).  `bmasks`
+        """Predicate eval wrapped in an `engine.mask` span (no predicate:
+        an all-true validity mask, not filter work, so no span).  `bmasks`
         maps (bloom name, column) -> this row group's pre-probed (L,)
         membership mask from the batched path's stacked probe."""
         if pred is None:
             return jnp.ones((L,), jnp.bool_)
-        tr = _tr()
-        if tr is not None:
-            tr.begin("filter", rg=rg, rows=L)
-        mask = self._eval(pred, cols, blooms, bmasks)
-        if tr is not None:
-            tr.end(name="filter")
+        with _span("engine.mask") as sp:
+            mask = self._eval(pred, cols, blooms, bmasks)
+            if sp is not None:
+                sp.set(rg=rg, rows=L)
         return mask
 
     # ------------------------------------------------------------------
@@ -474,6 +473,15 @@ class DatapathEngine:
         decode kernel; a mismatch quarantines the page key in the block
         store and raises typed — never returns garbage.  Legacy footers
         without checksums verify trivially (unverified fallback)."""
+        with _span("engine.storage_read") as sp:
+            got = self._read_verified(reader, rg, columns, stats)
+            if sp is not None:
+                sp.set(rg=rg, pages=len(got),
+                       bytes=sum(c.encoded_bytes() for c in got.values()))
+        return got
+
+    def _read_verified(self, reader, rg: int, columns,
+                       stats: ScanStats) -> Dict[str, EncodedColumn]:
         if self.faults is not None:
             return self.faults.read(self, reader, rg, columns, stats)
         got = reader.read_encoded(rg, columns)
@@ -540,14 +548,8 @@ class DatapathEngine:
                     stats.page_hit_bytes += page.encoded_bytes()
         fetched = False
         if missing:
-            tr = _tr()
-            if tr is not None:
-                tr.begin("fetch", rg=rg, columns=len(missing))
             got = self._storage_read(reader, rg, missing, stats)
-            nb = sum(c.encoded_bytes() for c in got.values())
-            if tr is not None:
-                tr.end(name="fetch", nbytes=nb)
-            stats.encoded_bytes += nb
+            stats.encoded_bytes += sum(c.encoded_bytes() for c in got.values())
             enc.update(got)
             fetched = True
             if mode in ("preloaded", "prefiltered"):
@@ -829,18 +831,16 @@ class DatapathEngine:
                 stats.decode_work.get(fe, 0) + L * self._fused_width(reader, rg, pred)
             )
             stats.kernel_launches += 1
-            tr = _tr()
-            if tr is not None:
-                tr.begin("decode_launch", rg=rg, encoding=fe, fused=True, rows=L)
-            fmask, _ = ops.fused_scan(
-                jnp.asarray(enc[pred.column].buffers["packed"]),
-                enc[pred.column].k,
-                lo,
-                hi,
-                backend=self.backend,
-            )
-            if tr is not None:
-                tr.end(name="decode_launch")
+            with _span("engine.decode") as sp:
+                fmask, _ = ops.fused_scan(
+                    jnp.asarray(enc[pred.column].buffers["packed"]),
+                    enc[pred.column].k,
+                    lo,
+                    hi,
+                    backend=self.backend,
+                )
+                if sp is not None:
+                    sp.set(rg=rg, encoding=fe, fused=True, pages=1, rows=L)
             fmask = fmask.reshape(-1)[:L]
             for name in proj:
                 if name in askip:
@@ -926,34 +926,37 @@ class DatapathEngine:
         # -- phase A: residency, page-tier fetch, fusability (rg order) ----
         # the front half is _prepare_row_group — the SAME code the
         # sequential scan_row_group runs, so the two paths cannot drift
-        slots = []
-        fetched: List[int] = []
-        for rg in rgs:
-            n, L, resident, enc, fuse, did_fetch = self._prepare_row_group(
-                reader, rg, plan, pred, mode, stats, pool=pool
-            )
-            askip = self._agg_skip(plan, pred, enc) if not resident else frozenset()
-            slot = {"rg": rg, "n": n, "L": L, "resident": resident,
-                    "enc": enc, "fuse": fuse, "askip": askip, "decode": []}
-            slots.append(slot)
-            if did_fetch:
-                fetched.append(rg)
-            if resident:
-                continue
-            # columns needing a fresh decode — non-mutating residency peek
-            # (presence checks touch no LRU order and count no hits; the
-            # counting lookups run in the finalize pass, in order).  Fused-
-            # aggregate pages (`askip`) never enter the decode buckets: the
-            # aggregate kernel unpacks them in VMEM.
-            for name in (proj if fuse is not None else need):
-                if name in askip:
+        with _span("engine.prepare") as sp:
+            slots = []
+            fetched: List[int] = []
+            for rg in rgs:
+                n, L, resident, enc, fuse, did_fetch = self._prepare_row_group(
+                    reader, rg, plan, pred, mode, stats, pool=pool
+                )
+                askip = self._agg_skip(plan, pred, enc) if not resident else frozenset()
+                slot = {"rg": rg, "n": n, "L": L, "resident": resident,
+                        "enc": enc, "fuse": fuse, "askip": askip, "decode": []}
+                slots.append(slot)
+                if did_fetch:
+                    fetched.append(rg)
+                if resident:
                     continue
-                key = self.rg_cache_key(reader, rg, name)
-                if pool is not None and key in pool:
-                    continue
-                if mode in ("preloaded", "prefiltered") and key in self.cache:
-                    continue
-                slot["decode"].append(name)
+                # columns needing a fresh decode — non-mutating residency peek
+                # (presence checks touch no LRU order and count no hits; the
+                # counting lookups run in the finalize pass, in order).  Fused-
+                # aggregate pages (`askip`) never enter the decode buckets: the
+                # aggregate kernel unpacks them in VMEM.
+                for name in (proj if fuse is not None else need):
+                    if name in askip:
+                        continue
+                    key = self.rg_cache_key(reader, rg, name)
+                    if pool is not None and key in pool:
+                        continue
+                    if mode in ("preloaded", "prefiltered") and key in self.cache:
+                        continue
+                    slot["decode"].append(name)
+            if sp is not None:
+                sp.set(rgs=len(rgs), fetched=len(fetched))
 
         # -- phase B: bucket compatible pages, one launch per bucket -------
         decoded, fmasks = self._launch_buckets(slots, pred, stats)
@@ -963,56 +966,59 @@ class DatapathEngine:
         bloom_by_rg = self._batch_bloom_probe(slots, pred, blooms, decoded)
 
         # -- finalize (strict rg order): hits, puts, stats, masks ----------
-        per_rg = []
-        for slot in slots:
-            rg, n, L = slot["rg"], slot["n"], slot["L"]
-            if slot["resident"]:
+        with _span("engine.finalize") as sp:
+            per_rg = []
+            for slot in slots:
+                rg, n, L = slot["rg"], slot["n"], slot["L"]
+                if slot["resident"]:
+                    cols = {}
+                    for name in need:
+                        cols[name] = self._serve_resident(
+                            reader, rg, name, L, mode, offload, pool, stats, fetched
+                        )
+                    mask = self._eval_mask(pred, cols, blooms, L, rg)
+                    per_rg.append((cols, mask & (jnp.arange(L) < n)))
+                    continue
+                enc = slot["enc"]
+                askip = slot["askip"]
                 cols = {}
+                if slot["fuse"] is not None:
+                    stats.fused = True
+                    fe = enc[pred.column].encoding.value
+                    stats.decode_work[fe] = (
+                        stats.decode_work.get(fe, 0)
+                        + L * self._fused_width(reader, rg, pred)
+                    )
+                    for name in proj:
+                        if name in askip:
+                            self._charge_agg_page(stats, enc[name], L)
+                            cols[name] = enc[name]
+                            continue
+                        arr, _ = self._decode_column(
+                            reader, rg, name, enc[name], L, offload=offload,
+                            pool=pool, stats=stats, precomputed=decoded.get((0, rg, name)),
+                        )
+                        cols[name] = arr
+                    mask = fmasks[(0, rg)]
+                else:
+                    for name in need:
+                        if name in askip:
+                            self._charge_agg_page(stats, enc[name], L)
+                            cols[name] = enc[name]
+                            continue
+                        arr, _ = self._decode_column(
+                            reader, rg, name, enc[name], L, offload=offload,
+                            pool=pool, stats=stats, precomputed=decoded.get((0, rg, name)),
+                        )
+                        cols[name] = arr
+                    mask = self._eval_mask(pred, cols, blooms, L, rg,
+                                           bmasks=bloom_by_rg.get((0, rg)))
+                mask = mask & (jnp.arange(L) < n)
                 for name in need:
-                    cols[name] = self._serve_resident(
-                        reader, rg, name, L, mode, offload, pool, stats, fetched
-                    )
-                mask = self._eval_mask(pred, cols, blooms, L, rg)
-                per_rg.append((cols, mask & (jnp.arange(L) < n)))
-                continue
-            enc = slot["enc"]
-            askip = slot["askip"]
-            cols = {}
-            if slot["fuse"] is not None:
-                stats.fused = True
-                fe = enc[pred.column].encoding.value
-                stats.decode_work[fe] = (
-                    stats.decode_work.get(fe, 0)
-                    + L * self._fused_width(reader, rg, pred)
-                )
-                for name in proj:
-                    if name in askip:
-                        self._charge_agg_page(stats, enc[name], L)
-                        cols[name] = enc[name]
-                        continue
-                    arr, _ = self._decode_column(
-                        reader, rg, name, enc[name], L, offload=offload,
-                        pool=pool, stats=stats, precomputed=decoded.get((0, rg, name)),
-                    )
-                    cols[name] = arr
-                mask = fmasks[(0, rg)]
-            else:
-                for name in need:
-                    if name in askip:
-                        self._charge_agg_page(stats, enc[name], L)
-                        cols[name] = enc[name]
-                        continue
-                    arr, _ = self._decode_column(
-                        reader, rg, name, enc[name], L, offload=offload,
-                        pool=pool, stats=stats, precomputed=decoded.get((0, rg, name)),
-                    )
-                    cols[name] = arr
-                mask = self._eval_mask(pred, cols, blooms, L, rg,
-                                       bmasks=bloom_by_rg.get((0, rg)))
-            mask = mask & (jnp.arange(L) < n)
-            for name in need:
-                cols.setdefault(name, None)
-            per_rg.append((cols, mask))
+                    cols.setdefault(name, None)
+                per_rg.append((cols, mask))
+            if sp is not None:
+                sp.set(rgs=len(rgs))
         return per_rg, fetched
 
     def _batch_bloom_probe(self, slots, pred, blooms, decoded) -> Dict[tuple, Dict]:
@@ -1028,31 +1034,34 @@ class DatapathEngine:
         probes = {(p.name, p.column): p for p in _expr_blooms(pred)
                   if p.name in blooms}
         out: Dict[tuple, Dict] = {}
-        for (name, column), probe in sorted(probes.items()):
-            entries = []  # (item, rg, L, nblk)
-            keys = []
-            for slot in slots:
-                if slot["resident"] or slot["fuse"] is not None:
+        with _span("engine.bloom") as sp:
+            for (name, column), probe in sorted(probes.items()):
+                entries = []  # (item, rg, L, nblk)
+                keys = []
+                for slot in slots:
+                    if slot["resident"] or slot["fuse"] is not None:
+                        continue
+                    item = slot.get("item", 0)
+                    arr = decoded.get((item, slot["rg"], column))
+                    if arr is None:
+                        continue  # pool/cache-served at finalize: per-rg probe
+                    L = slot["L"]
+                    entries.append((item, slot["rg"], L, L // RLE_OUT_BLOCK))
+                    keys.append(arr.astype(jnp.int32).reshape(-1, RLE_OUT_BLOCK))
+                if not entries:
                     continue
-                item = slot.get("item", 0)
-                arr = decoded.get((item, slot["rg"], column))
-                if arr is None:
-                    continue  # pool/cache-served at finalize: per-rg probe
-                L = slot["L"]
-                entries.append((item, slot["rg"], L, L // RLE_OUT_BLOCK))
-                keys.append(arr.astype(jnp.int32).reshape(-1, RLE_OUT_BLOCK))
-            if not entries:
-                continue
-            m = ops.bloom_probe(
-                jnp.concatenate(keys, axis=0), blooms[name], probe.n_hashes,
-                backend=self.backend,
-            )
-            s = 0
-            for item, rg, L, nblk in entries:
-                out.setdefault((item, rg), {})[(name, column)] = (
-                    m[s:s + nblk].reshape(-1)[:L]
+                m = ops.bloom_probe(
+                    jnp.concatenate(keys, axis=0), blooms[name], probe.n_hashes,
+                    backend=self.backend,
                 )
-                s += nblk
+                s = 0
+                for item, rg, L, nblk in entries:
+                    out.setdefault((item, rg), {})[(name, column)] = (
+                        m[s:s + nblk].reshape(-1)[:L]
+                    )
+                    s += nblk
+            if sp is not None:
+                sp.set(filters=len(probes))
         return out
 
     def _serve_resident(self, reader, rg, name, L, mode, offload, pool, stats,
@@ -1079,12 +1088,7 @@ class DatapathEngine:
                     stats.page_hits += 1
                     stats.page_hit_bytes += col.encoded_bytes()
             if col is None:
-                tr = _tr()
-                if tr is not None:
-                    tr.begin("fetch", rg=rg, columns=1)
                 col = self._storage_read(reader, rg, [name], stats)[name]
-                if tr is not None:
-                    tr.end(name="fetch", nbytes=col.encoded_bytes())
                 stats.encoded_bytes += col.encoded_bytes()
                 if rg not in fetched:
                     fetched.append(rg)
@@ -1149,42 +1153,42 @@ class DatapathEngine:
         decoded: Dict[tuple, jax.Array] = {}
         for bkey, items in buckets.items():
             bstats = items[0]["stats"]
-            tr = _tr()
-            if tr is not None:
-                launches0 = bstats.kernel_launches
-                pad0 = bstats.batch_pad_blocks
-                tr.begin("decode_launch",
-                         bucket="/".join(str(p) for p in bkey),
-                         pages=len(items))
-            decoded.update(self._decode_bucket(bkey, items, be, bstats))
-            if tr is not None:
-                tr.end(name="decode_launch",
-                       launches=bstats.kernel_launches - launches0,
-                       pad_blocks=bstats.batch_pad_blocks - pad0)
+            with _span("engine.decode") as sp:
+                if sp is not None:
+                    launches0 = bstats.kernel_launches
+                    pad0 = bstats.batch_pad_blocks
+                decoded.update(self._decode_bucket(bkey, items, be, bstats))
+                if sp is not None:
+                    sp.set(bucket="/".join(str(p) for p in bkey), pages=len(items),
+                           launches=bstats.kernel_launches - launches0,
+                           pad_blocks=bstats.batch_pad_blocks - pad0)
         fmasks: Dict[tuple, jax.Array] = {}
         for k, items in sorted(fused_items.items()):
             bstats = items[0]["stats"]
-            tr = _tr()
-            if tr is not None:
+            with _span("engine.decode") as sp:
                 pad0 = bstats.batch_pad_blocks
-                tr.begin("decode_launch", bucket=f"fused/k{k}",
-                         pages=len(items), fused=True)
-            packed = np.concatenate([it["packed"] for it in items], axis=0)
-            blocks = [it["packed"].shape[0] for it in items]
-            lo = np.concatenate(
-                [np.full(b, it["lo"], np.int32) for b, it in zip(blocks, items)])
-            hi = np.concatenate(
-                [np.full(b, it["hi"], np.int32) for b, it in zip(blocks, items)])
-            mask = ops.fused_scan_batch(packed, k, lo, hi, backend=be)
-            bstats.kernel_launches += 1
-            bstats.batch_pad_blocks += ops.bucket_blocks(packed.shape[0]) - packed.shape[0]
-            s = 0
-            for b, it in zip(blocks, items):
-                fmasks[(it["item"], it["rg"])] = mask[s:s + b].reshape(-1)[: it["L"]]
-                s += b
-            if tr is not None:
-                tr.end(name="decode_launch", launches=1,
-                       pad_blocks=bstats.batch_pad_blocks - pad0)
+                with _span("engine.stack") as st:
+                    packed = np.concatenate([it["packed"] for it in items], axis=0)
+                    blocks = [it["packed"].shape[0] for it in items]
+                    lo = np.concatenate(
+                        [np.full(b, it["lo"], np.int32) for b, it in zip(blocks, items)])
+                    hi = np.concatenate(
+                        [np.full(b, it["hi"], np.int32) for b, it in zip(blocks, items)])
+                    if st is not None:
+                        st.set(bucket=f"fused/k{k}", pages=len(items))
+                mask = ops.fused_scan_batch(packed, k, lo, hi, backend=be)
+                bstats.kernel_launches += 1
+                bstats.batch_pad_blocks += ops.bucket_blocks(packed.shape[0]) - packed.shape[0]
+                with _span("engine.split") as sl:
+                    s = 0
+                    for b, it in zip(blocks, items):
+                        fmasks[(it["item"], it["rg"])] = mask[s:s + b].reshape(-1)[: it["L"]]
+                        s += b
+                    if sl is not None:
+                        sl.set(pages=len(items))
+                if sp is not None:
+                    sp.set(bucket=f"fused/k{k}", pages=len(items), fused=True,
+                           launches=1, pad_blocks=bstats.batch_pad_blocks - pad0)
         return decoded, fmasks
 
     @staticmethod
@@ -1192,71 +1196,91 @@ class DatapathEngine:
         """Slice one bucket's stacked decode back into per-page (L,)
         columns, replicating the sequential pad-to-L / truncate-to-L."""
         res = {}
-        s = 0
-        for b, it in zip(blocks, items):
-            flat = out[s:s + b].reshape(-1)
-            L = it["L"]
-            if flat.shape[0] < L:
-                flat = jnp.pad(flat, (0, L - flat.shape[0]))
-            res[(it.get("item", 0), it["rg"], it["name"])] = flat[:L]
-            s += b
+        with _span("engine.split") as sp:
+            s = 0
+            for b, it in zip(blocks, items):
+                flat = out[s:s + b].reshape(-1)
+                L = it["L"]
+                if flat.shape[0] < L:
+                    flat = jnp.pad(flat, (0, L - flat.shape[0]))
+                res[(it.get("item", 0), it["rg"], it["name"])] = flat[:L]
+                s += b
+            if sp is not None:
+                sp.set(pages=len(items))
         return res
 
     def _decode_bucket(self, bkey, items, be, stats) -> Dict[tuple, jax.Array]:
+        """One bucket's launch: the host stacks its pages (`engine.stack`),
+        one counted launch decodes them (`ops.dispatch`), and the output is
+        sliced back into pages (`engine.split`)."""
         kind = bkey[0]
         if kind == "plain":
             # one host gather + ONE device put for the whole bucket (plain
             # has no kernel, so there is no jit trace to keep shape-stable
             # — no power-of-two padding, just the stacked transfer)
-            total = sum(it["L"] for it in items)
-            buf = np.zeros((total,), dtype=np.dtype(bkey[1]))
-            s = 0
-            for it in items:
-                v = it["col"].buffers["plain"]
-                buf[s:s + v.shape[0]] = v
-                s += it["L"]
+            with _span("engine.stack") as sp:
+                total = sum(it["L"] for it in items)
+                buf = np.zeros((total,), dtype=np.dtype(bkey[1]))
+                s = 0
+                for it in items:
+                    v = it["col"].buffers["plain"]
+                    buf[s:s + v.shape[0]] = v
+                    s += it["L"]
+                if sp is not None:
+                    sp.set(bucket="/".join(str(p) for p in bkey), pages=len(items))
             out = ops.device_put(buf)
             stats.kernel_launches += 1
-            res, s = {}, 0
-            for it in items:
-                res[(it.get("item", 0), it["rg"], it["name"])] = out[s:s + it["L"]]
-                s += it["L"]
+            res = {}
+            with _span("engine.split") as sp:
+                s = 0
+                for it in items:
+                    res[(it.get("item", 0), it["rg"], it["name"])] = out[s:s + it["L"]]
+                    s += it["L"]
+                if sp is not None:
+                    sp.set(pages=len(items))
             return res
         stats.kernel_launches += 1
+        with _span("engine.stack") as sp:
+            if kind == "rle":
+                values = np.concatenate(
+                    [it["col"].buffers["rle_values"] for it in items], axis=0)
+                ends = np.concatenate(
+                    [it["col"].buffers["rle_ends"] for it in items], axis=0)
+                blocks = [it["col"].buffers["rle_values"].shape[0] for it in items]
+            else:
+                packed = np.concatenate(
+                    [it["col"].buffers["packed"] for it in items], axis=0)
+                blocks = [it["col"].buffers["packed"].shape[0] for it in items]
+            if kind == "dict":
+                dicts_np = [
+                    d.astype(np.int32) if d.dtype.kind in "iu" else d
+                    for d in (it["col"].buffers["dictionary"] for it in items)
+                ]
+                # the dictionary axis is bucket-padded like the block axis:
+                # a raw per-call max width would re-trace the jitted batch
+                # decode once per distinct cardinality mix (per-block clip
+                # bounds make the zero padding unreachable, so this is free
+                # bit-wise)
+                dmax = ops.bucket_blocks(max(d.shape[0] for d in dicts_np))
+                dicts = np.zeros((len(items), dmax), dtype=np.dtype(bkey[2]))
+                sizes = np.zeros((len(items),), np.int32)
+                for i, d in enumerate(dicts_np):
+                    dicts[i, : d.shape[0]] = d
+                    sizes[i] = d.shape[0]
+                page = np.concatenate(
+                    [np.full(b, i, np.int32) for i, b in enumerate(blocks)])
+            elif kind == "delta":
+                bases = np.concatenate(
+                    [it["col"].buffers["bases"].astype(np.int32) for it in items])
+            if sp is not None:
+                sp.set(bucket="/".join(str(p) for p in bkey), pages=len(items))
         if kind == "bitpack":
-            packed = np.concatenate([it["col"].buffers["packed"] for it in items], axis=0)
-            blocks = [it["col"].buffers["packed"].shape[0] for it in items]
             out = ops.bitunpack_batch(packed, bkey[1], backend=be)
         elif kind == "dict":
-            packed = np.concatenate([it["col"].buffers["packed"] for it in items], axis=0)
-            blocks = [it["col"].buffers["packed"].shape[0] for it in items]
-            dicts_np = [
-                d.astype(np.int32) if d.dtype.kind in "iu" else d
-                for d in (it["col"].buffers["dictionary"] for it in items)
-            ]
-            # the dictionary axis is bucket-padded like the block axis: a
-            # raw per-call max width would re-trace the jitted batch decode
-            # once per distinct cardinality mix (per-block clip bounds make
-            # the zero padding unreachable, so this is free bit-wise)
-            dmax = ops.bucket_blocks(max(d.shape[0] for d in dicts_np))
-            dicts = np.zeros((len(items), dmax), dtype=np.dtype(bkey[2]))
-            sizes = np.zeros((len(items),), np.int32)
-            for i, d in enumerate(dicts_np):
-                dicts[i, : d.shape[0]] = d
-                sizes[i] = d.shape[0]
-            page = np.concatenate(
-                [np.full(b, i, np.int32) for i, b in enumerate(blocks)])
             out = ops.dict_decode_batch(packed, dicts, sizes, page, bkey[1], backend=be)
         elif kind == "delta":
-            packed = np.concatenate([it["col"].buffers["packed"] for it in items], axis=0)
-            blocks = [it["col"].buffers["packed"].shape[0] for it in items]
-            bases = np.concatenate(
-                [it["col"].buffers["bases"].astype(np.int32) for it in items])
             out = ops.delta_decode_batch(packed, bases, bkey[1], backend=be)
         else:  # rle
-            values = np.concatenate([it["col"].buffers["rle_values"] for it in items], axis=0)
-            ends = np.concatenate([it["col"].buffers["rle_ends"] for it in items], axis=0)
-            blocks = [it["col"].buffers["rle_values"].shape[0] for it in items]
             out = ops.rle_decode_batch(values, ends, backend=be)
         stats.batch_pad_blocks += ops.bucket_blocks(sum(blocks)) - sum(blocks)
         return self._split_flat(out, items, blocks)
@@ -1269,7 +1293,9 @@ class DatapathEngine:
         a single bucketed launch pass.
 
         Each item is one request's slice: {"reader", "rgs", "plan",
-        "pred", "blooms", "stats", "offload", "owner", "trace"} — the
+        "pred", "blooms", "stats", "offload", "owner", "trace"} — "trace"
+        being the (tracer, request trace, request id) the scheduler
+        publishes through `trace.set_slice` — the
         per-request state `ResumableScan.advance_batched` would have
         passed to `scan_row_groups_batched`.  Returns [(per_rg, fetched)]
         aligned with items, each element carrying that request's own
@@ -1321,131 +1347,141 @@ class DatapathEngine:
         slots_by_item: List[List[dict]] = []
         fetched_by_item: List[List[int]] = [[] for _ in items]
         pending: set = set()  # keys an EARLIER item decodes in this pass
-        for i, it in enumerate(items):
-            reader, plan, pred = it["reader"], it["plan"], it["pred"]
-            mode = it["offload"] or self.offload
-            stats = it["stats"]
-            need = plan.all_columns()
-            proj = plan.materialized_columns()
-            _owner(it)
-            _ctx(it)
-            slots = []
-            for rg in it["rgs"]:
-                keys = [self.rg_cache_key(reader, rg, name) for name in need]
-                if (pool is not None
-                        and all(k in pool or k in pending for k in keys)
-                        and any(k in pending for k in keys)):
-                    # every needed column is pooled or scheduled by an
-                    # earlier request in THIS pass: by this item's
-                    # finalize (strict item order) they are pool entries
-                    # — the same full residency the sequential order
-                    # would have seen after the earlier request's puts
-                    n = reader.row_group_meta(rg)["n"]
-                    slots.append({"rg": rg, "n": n, "L": padded_rows(n),
-                                  "resident": True, "enc": {}, "fuse": None,
-                                  "askip": frozenset(), "decode": [],
-                                  "item": i, "pred": pred, "stats": stats})
-                    continue
-                n, L, resident, enc, fuse, did_fetch = self._prepare_row_group(
-                    reader, rg, plan, pred, mode, stats, pool=pool
-                )
-                askip = self._agg_skip(plan, pred, enc) if not resident else frozenset()
-                slot = {"rg": rg, "n": n, "L": L, "resident": resident,
-                        "enc": enc, "fuse": fuse, "askip": askip, "decode": [],
-                        "item": i, "pred": pred, "stats": stats}
-                slots.append(slot)
-                if did_fetch:
-                    fetched_by_item[i].append(rg)
-                if resident:
-                    continue
-                for name in (proj if fuse is not None else need):
-                    if name in askip:
-                        continue  # fused-aggregate page: unpacked in-kernel
-                    key = self.rg_cache_key(reader, rg, name)
-                    if pool is not None and key in pool:
+        with _span("engine.prepare") as sp:
+            for i, it in enumerate(items):
+                reader, plan, pred = it["reader"], it["plan"], it["pred"]
+                mode = it["offload"] or self.offload
+                stats = it["stats"]
+                need = plan.all_columns()
+                proj = plan.materialized_columns()
+                _owner(it)
+                _ctx(it)
+                slots = []
+                for rg in it["rgs"]:
+                    keys = [self.rg_cache_key(reader, rg, name) for name in need]
+                    if (pool is not None
+                            and all(k in pool or k in pending for k in keys)
+                            and any(k in pending for k in keys)):
+                        # every needed column is pooled or scheduled by an
+                        # earlier request in THIS pass: by this item's
+                        # finalize (strict item order) they are pool entries
+                        # — the same full residency the sequential order
+                        # would have seen after the earlier request's puts
+                        n = reader.row_group_meta(rg)["n"]
+                        slots.append({"rg": rg, "n": n, "L": padded_rows(n),
+                                      "resident": True, "enc": {}, "fuse": None,
+                                      "askip": frozenset(), "decode": [],
+                                      "item": i, "pred": pred, "stats": stats})
                         continue
-                    if mode in ("preloaded", "prefiltered") and key in self.cache:
+                    n, L, resident, enc, fuse, did_fetch = self._prepare_row_group(
+                        reader, rg, plan, pred, mode, stats, pool=pool
+                    )
+                    askip = self._agg_skip(plan, pred, enc) if not resident else frozenset()
+                    slot = {"rg": rg, "n": n, "L": L, "resident": resident,
+                            "enc": enc, "fuse": fuse, "askip": askip, "decode": [],
+                            "item": i, "pred": pred, "stats": stats}
+                    slots.append(slot)
+                    if did_fetch:
+                        fetched_by_item[i].append(rg)
+                    if resident:
                         continue
-                    if pool is not None and key in pending:
-                        continue  # an earlier request decodes it; our
-                        # finalize serves it as a pool hit
-                    slot["decode"].append(name)
-                    pending.add(key)
-            slots_by_item.append(slots)
+                    for name in (proj if fuse is not None else need):
+                        if name in askip:
+                            continue  # fused-aggregate page: unpacked in-kernel
+                        key = self.rg_cache_key(reader, rg, name)
+                        if pool is not None and key in pool:
+                            continue
+                        if mode in ("preloaded", "prefiltered") and key in self.cache:
+                            continue
+                        if pool is not None and key in pending:
+                            continue  # an earlier request decodes it; our
+                            # finalize serves it as a pool hit
+                        slot["decode"].append(name)
+                        pending.add(key)
+                slots_by_item.append(slots)
+            if sp is not None:
+                sp.set(rgs=sum(len(it["rgs"]) for it in items), requests=len(items))
 
         # -- phase B: ONE bucket pass across every request's pages --------
-        # (bucket launch spans attribute to the first traced item)
+        # (bucket launch spans attribute to the first item the recorder
+        # traces, else to the first item)
         if tr_mod is not None:
-            first = next((it.get("trace") for it in items if it.get("trace")),
-                         None)
+            ctxs = [it["trace"] for it in items if it.get("trace")]
+            first = next((t for t in ctxs if t[1] is not None),
+                         ctxs[0] if ctxs else None)
             tr_mod.set_slice(*(first if first else (None, None)))
         all_slots = [s for slots in slots_by_item for s in slots]
         decoded, fmasks = self._launch_buckets(all_slots, None, None)
+        if tr_mod is not None:
+            tr_mod.set_slice(None, None)
 
         # -- finalize per item, in order: hits, puts, stats, masks --------
-        out = []
-        for i, it in enumerate(items):
-            reader, plan, pred = it["reader"], it["plan"], it["pred"]
-            blooms, stats = it["blooms"], it["stats"]
-            mode = it["offload"] or self.offload
-            offload = it["offload"]
-            need = plan.all_columns()
-            proj = plan.materialized_columns()
-            _owner(it)
-            _ctx(it)
-            per_rg = []
-            for slot in slots_by_item[i]:
-                rg, n, L = slot["rg"], slot["n"], slot["L"]
-                if slot["resident"]:
+        with _span("engine.finalize") as sp:
+            out = []
+            for i, it in enumerate(items):
+                reader, plan, pred = it["reader"], it["plan"], it["pred"]
+                blooms, stats = it["blooms"], it["stats"]
+                mode = it["offload"] or self.offload
+                offload = it["offload"]
+                need = plan.all_columns()
+                proj = plan.materialized_columns()
+                _owner(it)
+                _ctx(it)
+                per_rg = []
+                for slot in slots_by_item[i]:
+                    rg, n, L = slot["rg"], slot["n"], slot["L"]
+                    if slot["resident"]:
+                        cols = {}
+                        for name in need:
+                            cols[name] = self._serve_resident(
+                                reader, rg, name, L, mode, offload, pool, stats,
+                                fetched_by_item[i],
+                            )
+                        mask = self._eval_mask(pred, cols, blooms, L, rg)
+                        per_rg.append((cols, mask & (jnp.arange(L) < n)))
+                        continue
+                    enc = slot["enc"]
+                    askip = slot["askip"]
                     cols = {}
+                    if slot["fuse"] is not None:
+                        stats.fused = True
+                        fe = enc[pred.column].encoding.value
+                        stats.decode_work[fe] = (
+                            stats.decode_work.get(fe, 0)
+                            + L * self._fused_width(reader, rg, pred)
+                        )
+                        for name in proj:
+                            if name in askip:
+                                self._charge_agg_page(stats, enc[name], L)
+                                cols[name] = enc[name]
+                                continue
+                            arr, _ = self._decode_column(
+                                reader, rg, name, enc[name], L, offload=offload,
+                                pool=pool, stats=stats,
+                                precomputed=decoded.get((i, rg, name)),
+                            )
+                            cols[name] = arr
+                        mask = fmasks[(i, rg)]
+                    else:
+                        for name in need:
+                            if name in askip:
+                                self._charge_agg_page(stats, enc[name], L)
+                                cols[name] = enc[name]
+                                continue
+                            arr, _ = self._decode_column(
+                                reader, rg, name, enc[name], L, offload=offload,
+                                pool=pool, stats=stats,
+                                precomputed=decoded.get((i, rg, name)),
+                            )
+                            cols[name] = arr
+                        mask = self._eval_mask(pred, cols, blooms, L, rg)
+                    mask = mask & (jnp.arange(L) < n)
                     for name in need:
-                        cols[name] = self._serve_resident(
-                            reader, rg, name, L, mode, offload, pool, stats,
-                            fetched_by_item[i],
-                        )
-                    mask = self._eval_mask(pred, cols, blooms, L, rg)
-                    per_rg.append((cols, mask & (jnp.arange(L) < n)))
-                    continue
-                enc = slot["enc"]
-                askip = slot["askip"]
-                cols = {}
-                if slot["fuse"] is not None:
-                    stats.fused = True
-                    fe = enc[pred.column].encoding.value
-                    stats.decode_work[fe] = (
-                        stats.decode_work.get(fe, 0)
-                        + L * self._fused_width(reader, rg, pred)
-                    )
-                    for name in proj:
-                        if name in askip:
-                            self._charge_agg_page(stats, enc[name], L)
-                            cols[name] = enc[name]
-                            continue
-                        arr, _ = self._decode_column(
-                            reader, rg, name, enc[name], L, offload=offload,
-                            pool=pool, stats=stats,
-                            precomputed=decoded.get((i, rg, name)),
-                        )
-                        cols[name] = arr
-                    mask = fmasks[(i, rg)]
-                else:
-                    for name in need:
-                        if name in askip:
-                            self._charge_agg_page(stats, enc[name], L)
-                            cols[name] = enc[name]
-                            continue
-                        arr, _ = self._decode_column(
-                            reader, rg, name, enc[name], L, offload=offload,
-                            pool=pool, stats=stats,
-                            precomputed=decoded.get((i, rg, name)),
-                        )
-                        cols[name] = arr
-                    mask = self._eval_mask(pred, cols, blooms, L, rg)
-                mask = mask & (jnp.arange(L) < n)
-                for name in need:
-                    cols.setdefault(name, None)
-                per_rg.append((cols, mask))
-            out.append((per_rg, fetched_by_item[i]))
+                        cols.setdefault(name, None)
+                    per_rg.append((cols, mask))
+                out.append((per_rg, fetched_by_item[i]))
+            if sp is not None:
+                sp.set(rgs=sum(len(it["rgs"]) for it in items), requests=len(items))
         if tr_mod is not None:
             tr_mod.set_slice(None, None)
         return out
@@ -1708,7 +1744,6 @@ class ResumableScan:
                 gids = jnp.zeros((nblk, PACK_BLOCK), jnp.int32)
             metas.append((nblk, gids, mask.astype(jnp.int32).reshape(
                 nblk, PACK_BLOCK)))
-        tr = _tr()
         for src in agg_merge.agg_sources(self.plan.aggregates):
             # partition the slice: decoded pages (and the gids-as-values
             # bare count) stack into one grouped launch; never-decoded
@@ -1733,13 +1768,12 @@ class ResumableScan:
                 ], axis=0)
                 gids = jnp.concatenate([metas[i][1] for i in dec], axis=0)
                 m2 = jnp.concatenate([metas[i][2] for i in dec], axis=0)
-                if tr is not None:
-                    tr.begin("agg_launch", source=src or "*", pages=len(dec),
-                             rows=int(vals.shape[0]) * PACK_BLOCK)
-                planes = ops.grouped_agg_batch(
-                    vals, gids, m2, self._n_groups, backend=be)
-                if tr is not None:
-                    tr.end(name="agg_launch")
+                with _span("engine.agg") as sp:
+                    planes = ops.grouped_agg_batch(
+                        vals, gids, m2, self._n_groups, backend=be)
+                    if sp is not None:
+                        sp.set(source=src or "*", pages=len(dec),
+                               rows=int(vals.shape[0]) * PACK_BLOCK)
                 self.stats.kernel_launches += 1
                 nb = int(vals.shape[0])
                 self.stats.batch_pad_blocks += ops.bucket_blocks(nb) - nb
@@ -1762,12 +1796,11 @@ class ResumableScan:
                     for i in idxs
                 ], axis=0)
                 m2 = jnp.concatenate([metas[i][2] for i in idxs], axis=0)
-                if tr is not None:
-                    tr.begin("agg_launch", source=src, pages=len(idxs),
-                             fused=True, rows=int(packed.shape[0]) * PACK_BLOCK)
-                planes = ops.fused_agg_batch(packed, k, m2, backend=be)
-                if tr is not None:
-                    tr.end(name="agg_launch")
+                with _span("engine.agg") as sp:
+                    planes = ops.fused_agg_batch(packed, k, m2, backend=be)
+                    if sp is not None:
+                        sp.set(source=src, pages=len(idxs), fused=True,
+                               rows=int(packed.shape[0]) * PACK_BLOCK)
                 self.stats.kernel_launches += 1
                 nb = int(packed.shape[0])
                 self.stats.batch_pad_blocks += ops.bucket_blocks(nb) - nb
@@ -1784,9 +1817,18 @@ class ResumableScan:
                 )
 
     def _finish(self) -> None:
-        if self._agg:
-            self._finish_agg()
-            return
+        """Assemble the result once the last row group is folded in; the
+        `engine.finish` span covers it, and with it the wait for the
+        device's count of survivors (`rows_out`)."""
+        with _span("engine.finish") as sp:
+            if self._agg:
+                self._finish_agg()
+            else:
+                self._finish_rows()
+            if sp is not None:
+                sp.set(rows=self.stats.rows_out)
+
+    def _finish_rows(self) -> None:
         proj = self.plan.columns
         if not self._rgs:  # everything pruned — never cached (nothing scanned)
             # Empty columns must keep the schema's decoded dtypes (float32
@@ -1805,12 +1847,10 @@ class ResumableScan:
         mask = jnp.concatenate(self._per_rg_mask)
         count = jnp.sum(mask.astype(jnp.int32))
         if self.plan.compact:
-            tr = _tr()
-            if tr is not None:
-                tr.begin("filter", compact=True, rows=int(mask.shape[0]))
-            out_cols, mask, count = self.engine._compact(out_cols, mask)
-            if tr is not None:
-                tr.end(name="filter")
+            with _span("engine.compact") as sp:
+                out_cols, mask, count = self.engine._compact(out_cols, mask)
+                if sp is not None:
+                    sp.set(rows=int(mask.shape[0]))
         # result-DMA size: the projected columns + survivor mask actually
         # handed to the consumer (pred-only columns were dropped above —
         # decode→project)

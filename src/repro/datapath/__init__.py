@@ -31,10 +31,11 @@ telemetry.py  queue depth, decoded-bytes-saved, per-tenant p50/p99/p99.9,
               fair-share metrics (Jain index, held-request latency,
               window-retained bytes), estimated-vs-actual decode-cost
               ledger, per-tier store ledger
-trace.py      flight recorder: per-request span trees (admission / waits
-              / slices / fetch / decode / filter / reconcile), bounded
-              ring of completed traces, Chrome-trace export, and the
-              paper-anchored decode/filter/rest stage attribution
+trace.py      the span API every layer marks its work with (profiler
+              annotations + a span log for per-layer metrics) and the
+              flight recorder: per-request span trees, bounded ring of
+              completed traces, Chrome-trace export, host-time stage
+              attribution
 faults.py     storage fault plane: seedable deterministic fault schedules
               (FaultPlan), bounded retry/backoff/timeout/hedge policy
               (RetryPolicy + FaultInjector on the engine's storage-read
@@ -100,7 +101,6 @@ from repro.datapath.service import (  # noqa: F401
 )
 from repro.datapath.telemetry import Telemetry, jain_index, quantile  # noqa: F401
 from repro.datapath.trace import (  # noqa: F401
-    PAPER_FIG2_PCT,
     STAGES,
     FlightRecorder,
     RequestTrace,

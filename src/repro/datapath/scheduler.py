@@ -58,6 +58,7 @@ batch decode).
 
 from __future__ import annotations
 
+import time
 from typing import Dict, List, Tuple
 
 from repro.core import engine as _engine_mod
@@ -65,13 +66,15 @@ from repro.core.engine import ResumableScan
 from repro.datapath import trace
 from repro.datapath.faults import CorruptPageError, StorageFault
 from repro.datapath.policy import coalesce_compatible
+from repro.kernels import ops as _ops_mod
 
-# Install the engine's flight-recorder hook (engine.TRACE).  The engine
-# cannot import repro.datapath — that would close an import cycle through
-# the package __init__ — so the scheduler, which every traced slice flows
-# through, hands it the trace module once at import time.  Library users
-# who never import the datapath keep TRACE = None and pay nothing.
+# Install the span hook of the engine and the kernel API (`TRACE`).
+# Neither can import repro.datapath — that would close an import cycle
+# through the package __init__ — so the scheduler, which every served
+# slice flows through, hands them the trace module once at import time.
+# Library users who never import the datapath keep TRACE = None.
 _engine_mod.TRACE = trace
+_ops_mod.TRACE = trace
 
 
 def _retained_resident(service, req) -> bool:
@@ -144,6 +147,10 @@ def form_batch(service) -> List[Tuple[object, List[int]]]:
         req.started = True
         if req.first_tick == 0:
             req.first_tick = service._tick
+            if trace.profiling():  # submit -> first dispatch
+                trace.interval("pod.queued",
+                               time.perf_counter() - req.ticket.submitted_s,
+                               req=req.req_id)
         return False
 
     def take_rg(req) -> float:
@@ -320,28 +327,20 @@ def run_tick(service, batch: List[Tuple[object, List[int]]]) -> None:
             continue
         for req, rgs in group:
             pool.owner = req.tenant  # retained pins bill their decoder
-            # flight recorder: the slice span, plus the engine-side slice
-            # context (trace.set_slice) that lets decode/fetch/filter/store
-            # spans attach without a plumbed-through tracer argument
+            # the recorder's slice span, plus the slice context
+            # (trace.set_slice: request id and recorder trace) that engine,
+            # store and kernel spans attach to without a plumbed-through
+            # tracer argument
             rt = tracer.live(req.req_id) if tracer is not None else None
             if rt is not None:
                 tracer.end_wait(rt)  # waiting ends the moment we dispatch
                 tracer.begin(rt, "slice_dispatch", tick=service._tick,
                              rgs=len(rgs))
-                trace.set_slice(tracer, rt)
+            trace.set_slice(tracer, rt, req.req_id)
             try:
                 try:
                     if req.rs is None:  # first dispatch: pin the offload mode
-                        # service._choose_mode wraps the adaptive policy
-                        # with the circuit breaker's degraded-raw override
-                        mode = service._choose_mode(req)
-                        tel.inc(f"offload_{mode}")
-                        req.mode = mode
-                        req.rs = ResumableScan(
-                            service.engine, req.reader, req.plan, blooms=req.blooms,
-                            offload=mode, row_groups=req.row_groups,
-                            scan_tag=req.scan_tag,
-                        )
+                        _open_scan(service, req)
                     rs = req.rs
                     work0 = dict(rs.stats.decode_work)
                     launches0 = rs.stats.kernel_launches
@@ -394,15 +393,10 @@ def run_tick(service, batch: List[Tuple[object, List[int]]]) -> None:
                         launches = rs.stats.kernel_launches - launches0
                         tel.inc("decode_launches", launches)
                         tel.inc("decode_slice_rgs", len(rgs))  # both dispatch modes
-                        if rt is not None:
-                            tracer.begin(rt, "reconcile")
-                        actual_s = _reconcile_slice(
+                        _reconcile_slice(
                             service, req, work, launches,
                             peer_bytes=rs.stats.peer_bytes - peer0,
                             fault_s=rs.stats.fault_wait_s - fault0)
-                        if rt is not None:
-                            tracer.end(rt, name="reconcile",
-                                       launches=launches, actual_s=actual_s)
                 except Exception as e:  # noqa: BLE001 — isolate faulty requests
                     req.ticket.error = e
                     tel.inc("failed")
@@ -417,8 +411,8 @@ def run_tick(service, batch: List[Tuple[object, List[int]]]) -> None:
                     if res.stats.cache_hit:
                         tel.inc("prefiltered_hits")
             finally:
+                trace.set_slice(None, None)
                 if rt is not None:
-                    trace.set_slice(None, None)
                     tracer.end(rt, name="slice_dispatch", mode=req.mode or "")
         _finish_group(service, pool, fetches)
 
@@ -434,8 +428,25 @@ def _finish_group(service, pool, fetches) -> None:
         tel.inc("retained_redecode_saved_s", pool.retained_saved_s)
     if pool.rejected_puts:
         tel.inc("pool_rejected_puts", pool.rejected_puts)
+    with trace.span("sched.sim_fetch") as sp:
+        _simulate_fetch(service, fetches)
+        if sp is not None:
+            sp.set(slices=len(fetches))
 
-    _simulate_fetch(service, fetches)
+
+def _open_scan(service, req) -> None:
+    """A request's first dispatch: pin its offload mode (the adaptive
+    policy, behind the circuit breaker's degraded-raw override) and open
+    its resumable scan (predicate binding, pruning, the pre-filtered
+    tier's lookup)."""
+    with trace.span("sched.open"):
+        mode = service._choose_mode(req)
+        service.telemetry.inc(f"offload_{mode}")
+        req.mode = mode
+        req.rs = ResumableScan(
+            service.engine, req.reader, req.plan, blooms=req.blooms,
+            offload=mode, row_groups=req.row_groups, scan_tag=req.scan_tag,
+        )
 
 
 def _run_group_stacked(service, group, pool, fetches) -> None:
@@ -467,27 +478,18 @@ def _run_group_stacked(service, group, pool, fetches) -> None:
             tracer.end_wait(rt)  # waiting ends the moment we dispatch
             tracer.begin(rt, "slice_dispatch", tick=service._tick,
                          rgs=len(rgs))
-            trace.set_slice(tracer, rt)
+        trace.set_slice(tracer, rt, req.req_id)
         try:
             if req.rs is None:  # first dispatch: pin the offload mode
-                mode = service._choose_mode(req)
-                tel.inc(f"offload_{mode}")
-                req.mode = mode
-                req.rs = ResumableScan(
-                    engine, req.reader, req.plan, blooms=req.blooms,
-                    offload=mode, row_groups=req.row_groups,
-                    scan_tag=req.scan_tag,
-                )
+                _open_scan(service, req)
         except Exception as e:  # noqa: BLE001 — isolate faulty requests
             req.ticket.error = e
             tel.inc("failed")
             if rt is not None:
-                trace.set_slice(None, None)
                 tracer.end(rt, name="slice_dispatch", mode=req.mode or "")
             continue
         finally:
-            if rt is not None:
-                trace.set_slice(None, None)
+            trace.set_slice(None, None)
         rs = req.rs
         live.append((req, rgs, rt, dict(rs.stats.decode_work),
                      rs.stats.kernel_launches, rs.stats.decoded_bytes,
@@ -498,7 +500,7 @@ def _run_group_stacked(service, group, pool, fetches) -> None:
                 "reader": req.reader, "rgs": list(rgs), "plan": rs.plan,
                 "pred": rs.pred, "blooms": rs.blooms, "stats": rs.stats,
                 "offload": rs.offload, "owner": req.tenant,
-                "trace": (tracer, rt) if rt is not None else None,
+                "trace": (tracer, rt, req.req_id),
             })
 
     # -- ONE bucket pass across every request's slice -------------------
@@ -520,8 +522,7 @@ def _run_group_stacked(service, group, pool, fetches) -> None:
     for req, rgs, rt, work0, launches0, dec0, peer0, fault0 in live:
         pool.owner = req.tenant
         rs = req.rs
-        if rt is not None:
-            trace.set_slice(tracer, rt)
+        trace.set_slice(tracer, rt, req.req_id)
         try:
             try:
                 idx = item_of.get(req.req_id)
@@ -549,15 +550,10 @@ def _run_group_stacked(service, group, pool, fetches) -> None:
                     launches = rs.stats.kernel_launches - launches0
                     tel.inc("decode_launches", launches)
                     tel.inc("decode_slice_rgs", len(rgs))
-                    if rt is not None:
-                        tracer.begin(rt, "reconcile")
-                    actual_s = _reconcile_slice(
+                    _reconcile_slice(
                         service, req, work, launches,
                         peer_bytes=rs.stats.peer_bytes - peer0,
                         fault_s=rs.stats.fault_wait_s - fault0)
-                    if rt is not None:
-                        tracer.end(rt, name="reconcile",
-                                   launches=launches, actual_s=actual_s)
             except Exception as e:  # noqa: BLE001 — isolate faulty requests
                 req.ticket.error = e
                 tel.inc("failed")
@@ -572,8 +568,8 @@ def _run_group_stacked(service, group, pool, fetches) -> None:
                 if res.stats.cache_hit:
                     tel.inc("prefiltered_hits")
         finally:
+            trace.set_slice(None, None)
             if rt is not None:
-                trace.set_slice(None, None)
                 tracer.end(rt, name="slice_dispatch", mode=req.mode or "")
 
 
@@ -605,21 +601,24 @@ def _reconcile_slice(service, req, work: Dict[str, int], launches: int = 0,
     recovery work can never buy share from healthy tenants — and the
     sched + recon == actual telemetry invariant keeps holding under
     chaos."""
-    charged_s, raw_s = req.charged_s, req.charged_raw_s
-    req.charged_s = req.charged_raw_s = 0.0
-    actual_s = sum(
-        service.cost_model.decode_seconds(nbytes, encoding)
-        for encoding, nbytes in work.items()
-    ) + service.cost_model.launch_seconds(launches)
-    if peer_bytes:
-        peer_s = service.cost_model.peer_fetch_seconds(peer_bytes)
-        actual_s += peer_s
-        service.telemetry.observe_peer(req.tenant, peer_bytes, peer_s)
-    if fault_s:
-        actual_s += fault_s
-        service.telemetry.observe_fault_wait(req.tenant, fault_s)
-    service._vreconcile(req.tenant, charged_s, raw_s, actual_s,
-                        table=req.reader.path)
+    with trace.span("sched.reconcile") as sp:
+        charged_s, raw_s = req.charged_s, req.charged_raw_s
+        req.charged_s = req.charged_raw_s = 0.0
+        actual_s = sum(
+            service.cost_model.decode_seconds(nbytes, encoding)
+            for encoding, nbytes in work.items()
+        ) + service.cost_model.launch_seconds(launches)
+        if peer_bytes:
+            peer_s = service.cost_model.peer_fetch_seconds(peer_bytes)
+            actual_s += peer_s
+            service.telemetry.observe_peer(req.tenant, peer_bytes, peer_s)
+        if fault_s:
+            actual_s += fault_s
+            service.telemetry.observe_fault_wait(req.tenant, fault_s)
+        service._vreconcile(req.tenant, charged_s, raw_s, actual_s,
+                            table=req.reader.path)
+        if sp is not None:
+            sp.set(launches=launches, actual_s=actual_s)
     return actual_s
 
 

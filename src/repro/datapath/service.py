@@ -50,6 +50,7 @@ from repro.core.cache import BlockCache
 from repro.core.engine import DatapathEngine, ScanResult
 from repro.core.plan import ScanPlan, bind_expr
 from repro.core.zonemap import prune_and_estimate
+from repro.datapath import trace
 from repro.datapath.blockstore import BlockStore
 from repro.datapath.costmodel import CostModel
 from repro.datapath.faults import (
@@ -407,6 +408,14 @@ class Pod:
         disambiguates the request's prefiltered-cache identity — fabric
         sub-scans tag with their row-group subset so a cached sub-result
         can never serve a DIFFERENT subset after a drain re-partitions."""
+        with trace.span("pod.submit") as sp:
+            ticket = self._admit(tenant, reader, plan, blooms, row_groups, scan_tag)
+            if sp is not None:
+                sp.set(tick=self._tick, req=ticket.req_id)
+        return ticket
+
+    def _admit(self, tenant: str, reader, plan: ScanPlan, blooms: Optional[Dict],
+               row_groups, scan_tag) -> Ticket:
         tr = self.tracer
         t_tr0 = tr.clock() if tr is not None else 0.0  # trace time base
         self.telemetry.inc("submitted")
@@ -554,6 +563,13 @@ class Pod:
         slices (scheduler.form_batch) and execute it coalesced.  A request
         completes the tick its last row group lands; a large scan may span
         many ticks (preemption points).  Returns requests completed."""
+        with trace.span("pod.tick") as sp:
+            n = self._tick_once()
+            if sp is not None:
+                sp.set(tick=self._tick, completed=n)
+        return n
+
+    def _tick_once(self) -> int:
         self._tick += 1
         # expire decode-window pins whose hold window ended (ephemeral raw
         # decodes drop; promoted entries merely become evictable)
@@ -572,49 +588,58 @@ class Pod:
         self.telemetry.sample_queue_depth(len(self.queue))
         if not self.queue:
             return 0
-        batch = form_batch(self)
+        with trace.span("sched.form_batch") as sp:
+            batch = form_batch(self)
+            if sp is not None:
+                sp.set(slices=len(batch), queued=len(self.queue))
         t0 = time.perf_counter()
         if batch:
             run_tick(self, batch)
         now = time.perf_counter()
         self.telemetry.observe_tick(now - t0)
-        done: List[ScanRequest] = []
-        failed = 0
-        for req in self.queue:
-            if req.ticket.error is None and (req.rs is None or req.rs.result is None):
-                continue  # still in flight (or held) — stays queued
-            done.append(req)
-            req.ticket.status = "error" if req.ticket.error is not None else "done"
-            req.ticket.done_s = now
-            req.ticket.done_tick = self._tick
-            self.telemetry.observe_latency(req.tenant, now - req.ticket.submitted_s)
-            failed += req.ticket.status == "error"
-            if self._tick > req.first_tick > 0:
-                self.telemetry.inc("split_scans")  # preempted across ticks
-            res = req.ticket.result
-            if self.tracer is not None:
-                # close the root span at the request's terminal tick and
-                # push the trace into the flight recorder's bounded ring
-                self.tracer.finish(
-                    req.req_id, req.ticket.status, done_tick=self._tick,
-                    mode=req.mode or "", held_ticks=req.held_ticks,
-                    rows_out=res.stats.rows_out if res is not None else 0,
-                )
-            if res is not None:
-                # reconcile the admission estimate against bytes actually
-                # pulled: cache-resident and pool-coalesced scans fetch less
-                # (often zero), and quotas meter the storage->NIC hop
-                state = self._state(req.tenant)
-                over_b = req.est_bytes - res.stats.encoded_bytes
-                if over_b > 0:
-                    state.used_bytes = max(0, state.used_bytes - over_b)
-                over_r = req.est_rows - res.stats.rows_out
-                if over_r > 0:
-                    state.used_rows = max(0, state.used_rows - over_r)
-        if done:
-            done_ids = {r.req_id for r in done}
-            self.queue = [r for r in self.queue if r.req_id not in done_ids]
-        self.telemetry.inc("completed", len(done) - failed)
+        # completion: a request is done the tick its last row group lands.
+        # Its latency is submit -> result enqueued: the result's arrays may
+        # still be computing on the device when the ticket turns "done".
+        with trace.span("pod.complete") as sp:
+            done: List[ScanRequest] = []
+            failed = 0
+            for req in self.queue:
+                if req.ticket.error is None and (req.rs is None or req.rs.result is None):
+                    continue  # still in flight (or held) — stays queued
+                done.append(req)
+                req.ticket.status = "error" if req.ticket.error is not None else "done"
+                req.ticket.done_s = now
+                req.ticket.done_tick = self._tick
+                self.telemetry.observe_latency(req.tenant, now - req.ticket.submitted_s)
+                failed += req.ticket.status == "error"
+                if self._tick > req.first_tick > 0:
+                    self.telemetry.inc("split_scans")  # preempted across ticks
+                res = req.ticket.result
+                if self.tracer is not None:
+                    # close the root span at the request's terminal tick and
+                    # push the trace into the flight recorder's bounded ring
+                    self.tracer.finish(
+                        req.req_id, req.ticket.status, done_tick=self._tick,
+                        mode=req.mode or "", held_ticks=req.held_ticks,
+                        rows_out=res.stats.rows_out if res is not None else 0,
+                    )
+                if res is not None:
+                    # reconcile the admission estimate against bytes actually
+                    # pulled: cache-resident and pool-coalesced scans fetch less
+                    # (often zero), and quotas meter the storage->NIC hop
+                    state = self._state(req.tenant)
+                    over_b = req.est_bytes - res.stats.encoded_bytes
+                    if over_b > 0:
+                        state.used_bytes = max(0, state.used_bytes - over_b)
+                    over_r = req.est_rows - res.stats.rows_out
+                    if over_r > 0:
+                        state.used_rows = max(0, state.used_rows - over_r)
+            if done:
+                done_ids = {r.req_id for r in done}
+                self.queue = [r for r in self.queue if r.req_id not in done_ids]
+            self.telemetry.inc("completed", len(done) - failed)
+            if sp is not None:
+                sp.set(requests=len(done))
         return len(done)
 
     def drain(self) -> int:

@@ -103,7 +103,9 @@ class Telemetry:
         self._tick_seconds.append(seconds)
 
     def observe_latency(self, tenant: str, seconds: float) -> None:
-        """One request's submit->complete wall latency for `tenant`."""
+        """One request's latency for `tenant`: host seconds from submit to
+        the tick that enqueued its result (`Pod.tick`), not to the result
+        being ready on the device — launches are asynchronous."""
         dq = self._tenant_latency.setdefault(
             tenant, collections.deque(maxlen=self._max_samples)
         )

@@ -1,65 +1,87 @@
-"""Datapath flight recorder — per-request span tracing with paper-anchored
-stage attribution and exportable timelines.
+"""Datapath tracing: one span API for every layer of the served path,
+and the per-request flight recorder.
 
-The paper's headline claim is a TIME-ATTRIBUTION claim: decode is 46% of
-TPC-H runtime on Parquet, filter 17% (Fig. 2).  Telemetry reports those
-numbers fleet-wide; this module makes them a PER-REQUEST measurement.
-Every admitted request (subject to `sample_rate`) carries a span tree —
+`span(name, **counts)` is the one way a layer marks its work, from
+`Pod.submit` down to the kernel API:
+
+    with trace.span("engine.storage_read") as sp:
+        pages = reader.read_encoded(rg, columns)
+        if sp is not None:
+            sp.set(pages=len(pages), bytes=nbytes)
+
+It makes one check: is the JAX profiler collecting
+(`TraceAnnotation.is_enabled()`), or is a flight-recorder slice live
+(`_CUR`)?  When neither, it returns a shared null context whose `as`
+value is None, so a call site builds no counts and reads no clock.  When
+the profiler collects, the span
+
+  - enters a `jax.profiler.TraceAnnotation`, so it lands in the trace's
+    host plane on the same clock as the device's `XLA Ops` line, with its
+    counts (and the request id of the slice, `req`) as metadata;
+  - appends (name, thread, t0_ns, t1_ns, counts) to `LOG`, a bounded
+    in-memory log of the latest profiler session, which per-layer metrics
+    read.  Its times are `time.perf_counter_ns()`, and it counts the spans
+    it drops when full.
+
+When a recorder slice is live the span is also a node of that request's
+span tree.  Span names are `<layer>.<phase>`: `pod.*` (service front),
+`sched.*` (scheduler), `engine.*` (scan engine) and `ops.dispatch` (one
+counted launch of the kernel API).
+
+The flight recorder keeps a span tree per admitted request (subject to
+`sample_rate`) —
 
     request                     submit() -> terminal ticket status
       admission                 metadata-only estimate + quota checks
       wfq_wait | hold_window    queued ticks, by WHY the request waited
       slice_dispatch            one per scheduler slice (run_tick)
-        fetch                   storage->NIC pull of encoded pages
-        decode_launch           one per device dispatch (bucket or column)
-        filter                  predicate eval / stream compaction
-        reconcile               actual-cost re-billing of virtual time
+        <layer spans>           engine.storage_read, engine.decode, ...
         store_hit / evict / sim_fetch   zero-duration instant events
 
 — and the completed trees live in a bounded ring (`FlightRecorder`,
-last-N requests, fixed memory, always on).  Exporters: Chrome/Perfetto
+last-N requests, fixed memory).  Exporters: Chrome/Perfetto
 `trace_event` JSON (one pid per tenant, one tid per request) and a
-deterministic stage-attribution report whose `decode_pct`/`filter_pct`/
-`rest_pct` line up against the paper's 46/17 split (PAPER_FIG2_PCT).
+deterministic stage-attribution report in seconds per stage.
 
-Cost discipline (DESIGN.md §13): everything here is pure stdlib, and the
-hot path is gated so an untraced run allocates NOTHING — the engine's
-call sites check `trace._CUR is None` (one module-attribute load) before
-building any kwargs.  The scheduler publishes the active request's trace
-via `set_slice()` around each slice, so engine/blockstore code needs no
-plumbed-through tracer argument.  Tracing must never perturb results:
-bit-identity of scan output with tracing on/off is property-tested in
-tests/test_trace_props.py.
+All recorder times are HOST time (`time.perf_counter`).  Kernel launches
+are asynchronous on an accelerator, so a stage's seconds are the host
+work of that stage plus whatever device wait happens to fall in it (a
+launch span times the enqueue, not the kernel); device time comes from
+the profiler's trace.  Tracing must never perturb results: bit-identity
+of scan output with the recorder and the profiler on or off is
+property-tested in tests/test_trace_props.py.
 """
 
 from __future__ import annotations
 
 import collections
+import contextlib
 import json
+import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Dict, List, Optional
 
-# The paper's Fig. 2 TPC-H-on-Parquet breakdown — the anchor every
-# stage-attribution report is printed against.
-PAPER_FIG2_PCT = {"decode": 46.0, "filter": 17.0, "rest": 37.0}
+from jax.profiler import TraceAnnotation
 
 # span name -> attribution stage.  Children of a mapped span are NOT
-# recursed into (a store_hit inside a fetch span must not double-bill),
+# recursed into (a store_hit inside a storage read must not double-bill),
 # so stage seconds over one trace can never exceed the root wall time.
 STAGE_OF = {
     "admission": "admission",
     "hold_window": "hold_window",
     "wfq_wait": "wfq_wait",
-    "fetch": "fetch",
-    "decode_launch": "decode",
-    "filter": "filter",
-    "reconcile": "reconcile",
+    "engine.storage_read": "fetch",
+    "engine.decode": "decode",
+    "engine.mask": "filter",
+    "engine.bloom": "filter",
+    "engine.compact": "filter",
+    "sched.reconcile": "reconcile",
 }
 STAGES = ("admission", "hold_window", "wfq_wait", "fetch", "decode",
           "filter", "reconcile")
 
 
-def _span(name: str, t0: float, attrs: dict) -> dict:
+def _node(name: str, t0: float, attrs: dict) -> dict:
     return {"name": name, "t0": t0, "t1": None, "args": attrs, "children": []}
 
 
@@ -81,7 +103,7 @@ class RequestTrace:
         self.tenant = tenant
         self.table = table
         self.status = "queued"
-        self.root = _span("request", t0, attrs)
+        self.root = _node("request", t0, attrs)
         self.stack: List[dict] = [self.root]
         self.n_spans = 1
         self.dropped_spans = 0  # spans refused by the max_spans cap
@@ -110,10 +132,9 @@ class FlightRecorder:
     # -- stage attribution -------------------------------------------------
     def report(self) -> dict:
         """Deterministic stage-attribution report over the ring: one
-        summary per recorded request (ring order), fleet stage seconds
-        and time-weighted decode/filter/rest percentages, a per-tenant
-        rollup, and the paper's Fig. 2 anchor for side-by-side reading.
-        Every dict is key-sorted; values are plain floats/ints."""
+        summary per recorded request (ring order), fleet host seconds per
+        stage and a per-tenant rollup.  Every dict is key-sorted; values
+        are plain floats/ints."""
         traces = list(self._ring)
         stage_s = {s: 0.0 for s in STAGES}
         wall = 0.0
@@ -130,18 +151,7 @@ class FlightRecorder:
                 stage_s[s] += v
                 bt["stage_s"][s] += v
         for bt in by_tenant.values():
-            w = bt["wall_s"]
-            bt["stage_pct"] = {
-                s: (100.0 * v / w if w > 0 else 0.0)
-                for s, v in sorted(bt["stage_s"].items())
-            }
-            bt["decode_pct"] = bt["stage_pct"]["decode"]
-            bt["filter_pct"] = bt["stage_pct"]["filter"]
-            bt["rest_pct"] = max(
-                0.0, 100.0 - bt["decode_pct"] - bt["filter_pct"])
             bt["stage_s"] = dict(sorted(bt["stage_s"].items()))
-        decode_pct = 100.0 * stage_s["decode"] / wall if wall > 0 else 0.0
-        filter_pct = 100.0 * stage_s["filter"] / wall if wall > 0 else 0.0
         return {
             "capacity": self.capacity,
             "completed": self.completed,
@@ -149,13 +159,7 @@ class FlightRecorder:
             "requests": [rt.summary for rt in traces if rt.summary],
             "wall_s": wall,
             "stage_s": dict(sorted(stage_s.items())),
-            "stage_pct": {
-                "decode": decode_pct,
-                "filter": filter_pct,
-                "rest": max(0.0, 100.0 - decode_pct - filter_pct),
-            },
             "by_tenant": dict(sorted(by_tenant.items())),
-            "paper_fig2_pct": dict(sorted(PAPER_FIG2_PCT.items())),
         }
 
     # -- Chrome/Perfetto export --------------------------------------------
@@ -278,7 +282,7 @@ class Tracer:
             rt.dropped_spans += 1
             rt.drop_depth += 1  # the matching end() must not pop a real span
             return
-        sp = _span(name, self.clock(), attrs)
+        sp = _node(name, self.clock(), attrs)
         rt.stack[-1]["children"].append(sp)
         rt.stack.append(sp)
         rt.n_spans += 1
@@ -306,7 +310,7 @@ class Tracer:
             rt.dropped_spans += 1
             return
         now = self.clock()
-        sp = _span(name, now, attrs)
+        sp = _node(name, now, attrs)
         sp["t1"] = now
         rt.stack[-1]["children"].append(sp)
         rt.n_spans += 1
@@ -317,7 +321,7 @@ class Tracer:
         if rt.n_spans >= self.max_spans:
             rt.dropped_spans += 1
             return
-        sp = _span(name, t0, attrs)
+        sp = _node(name, t0, attrs)
         sp["t1"] = max(t1, t0)
         rt.stack[-1]["children"].append(sp)
         rt.n_spans += 1
@@ -360,8 +364,6 @@ class Tracer:
         for c in rt.root["children"]:
             walk(c)
         wall = rt.root["t1"] - rt.root["t0"]
-        decode_pct = 100.0 * stages["decode"] / wall if wall > 0 else 0.0
-        filter_pct = 100.0 * stages["filter"] / wall if wall > 0 else 0.0
         args = rt.root["args"]
         return {
             "req_id": rt.req_id,
@@ -375,9 +377,6 @@ class Tracer:
             "wall_s": wall,
             "stages_s": dict(sorted(stages.items())),
             "attributed_s": sum(stages.values()),
-            "decode_pct": decode_pct,
-            "filter_pct": filter_pct,
-            "rest_pct": max(0.0, 100.0 - decode_pct - filter_pct),
             "spans": rt.n_spans,
             "dropped_spans": rt.dropped_spans,
         }
@@ -396,33 +395,139 @@ class Tracer:
 
 
 # ---------------------------------------------------------------------------
-# module-level slice context — how the engine/blockstore emit spans without
-# a plumbed-through tracer argument
+# module-level slice context and the span API
 # ---------------------------------------------------------------------------
-# The scheduler sets (_CUR_TRACER, _CUR) around each dispatched slice; the
-# engine's hot loops gate on `trace._CUR is None` (one attribute load, no
-# allocation) before building span kwargs.  Deterministically single-
-# threaded by construction (DESIGN.md §7), so one slot suffices.
+# The scheduler publishes the slice that is executing — its request id,
+# and its recorder trace when the request is sampled — around each slice,
+# so engine, store and kernel code need no plumbed-through tracer.  A Pod
+# runs its ticks on one thread at a time (DESIGN.md §7), so one slot
+# suffices.
 _CUR: Optional[RequestTrace] = None
 _CUR_TRACER: Optional[Tracer] = None
+_REQ: Optional[int] = None
+
+profiling = TraceAnnotation.is_enabled  # is the JAX profiler collecting?
+
+NULL = contextlib.nullcontext()  # what `span` returns when nothing records
 
 
-def set_slice(tracer: Optional[Tracer], rt: Optional[RequestTrace]) -> None:
-    """Publish (or clear, with Nones) the request whose slice is executing."""
-    global _CUR, _CUR_TRACER
-    _CUR, _CUR_TRACER = rt, tracer
+def set_slice(tracer: Optional[Tracer], rt: Optional[RequestTrace],
+              req: Optional[int] = None) -> None:
+    """Publish (or clear, with Nones) the slice that is executing: the
+    request's recorder trace, if sampled, and its id."""
+    global _CUR, _CUR_TRACER, _REQ
+    _CUR, _CUR_TRACER, _REQ = rt, tracer, req
 
 
-def begin(name: str, **attrs) -> None:
-    if _CUR is not None:
-        _CUR_TRACER.begin(_CUR, name, **attrs)
+class SpanLog:
+    """The spans of the latest profiler session, for per-layer metrics:
+    (name, thread id, t0_ns, t1_ns, counts) tuples on the host's
+    `perf_counter_ns` clock.  A session opens at the first span that sees
+    the profiler collecting and closes at the first that sees it stopped
+    (or at `span_log()`); opening clears the log.  Past `capacity` spans
+    it drops new ones and counts them."""
+
+    def __init__(self, capacity: int = 1 << 16):
+        self.capacity = capacity
+        self.spans: List[tuple] = []
+        self.dropped = 0
+        self.session = 0  # sessions opened so far in this process
+        self.active = False  # the session is still collecting
+        self._lock = threading.Lock()
+
+    def sync(self, on: bool) -> None:
+        """Follow the profiler: open a fresh session or close the one open."""
+        with self._lock:
+            if on and not self.active:
+                self.spans = []
+                self.dropped = 0
+                self.session += 1
+            self.active = on
+
+    def add(self, name: str, t0: int, t1: int, counts: dict) -> None:
+        if len(self.spans) >= self.capacity:
+            self.dropped += 1
+        else:
+            self.spans.append((name, threading.get_ident(), t0, t1, counts))
 
 
-def end(name: Optional[str] = None, **attrs) -> None:
-    if _CUR is not None:
-        _CUR_TRACER.end(_CUR, name=name, **attrs)
+LOG = SpanLog()
+
+
+def span_log() -> SpanLog:
+    """The log of the latest profiler session; `active` while it collects."""
+    _logging()
+    return LOG
+
+
+def _logging() -> bool:
+    """Is the profiler collecting?  Keeps the log's session in step."""
+    on = profiling()
+    if on != LOG.active:
+        LOG.sync(on)
+    return on
+
+
+class Span:
+    """One live span; see `span`.  `set(**counts)` adds counts known only
+    once the work is done (they reach the profiler as metadata too)."""
+
+    __slots__ = ("name", "counts", "_ann", "_t0", "_rt", "_tracer")
+
+    def __init__(self, name: str, counts: dict):
+        self.name = name
+        self.counts = counts
+        self._ann = None
+        self._rt = None
+
+    def set(self, **counts) -> None:
+        self.counts.update(counts)
+        if self._ann is not None:
+            self._ann.set_metadata(**counts)
+
+    def __enter__(self) -> "Span":
+        rt = _CUR
+        if rt is not None:
+            self._rt, self._tracer = rt, _CUR_TRACER
+            self._tracer.begin(rt, self.name, **self.counts)
+        if _logging():
+            meta = self.counts if _REQ is None else dict(self.counts, req=_REQ)
+            self._ann = TraceAnnotation(self.name, **meta)
+            self._ann.__enter__()
+            self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        ann = self._ann
+        if ann is not None:
+            t1 = time.perf_counter_ns()
+            ann.__exit__(*exc)
+            LOG.add(self.name, self._t0, t1, self.counts)
+        if self._rt is not None:
+            self._tracer.end(self._rt, name=self.name, **self.counts)
+        return False
+
+
+def span(name: str, **counts):
+    """A span around one phase of one layer: a `Span` when the profiler
+    collects or a recorder slice is live, else `NULL`.  Use it as a
+    context manager; its `as` value is None when nothing records."""
+    if _CUR is None and not profiling():
+        return NULL
+    return Span(name, counts)
+
+
+def interval(name: str, seconds: float, **counts) -> None:
+    """Log a span that ends now and began `seconds` ago (a wait measured
+    after the fact, such as `pod.queued`).  Log only: the profiler cannot
+    take a span back-dated.  A no-op unless the profiler collects."""
+    if _logging():
+        t1 = time.perf_counter_ns()
+        LOG.add(name, t1 - int(seconds * 1e9), t1, counts)
 
 
 def event(name: str, **attrs) -> None:
+    """A zero-duration instant in the live recorder slice (store_hit,
+    evict, fault, ...); recorder only."""
     if _CUR is not None:
         _CUR_TRACER.event(_CUR, name, **attrs)

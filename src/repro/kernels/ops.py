@@ -28,11 +28,13 @@ the dispatch-overhead wall the per-backend cost-model tables measure.
 The module-level dispatch counter underneath `dispatch_count()` is the
 benchmarks' device-dispatch metric: each public entry here counts the
 launches it issues (a batch call counts ONE however many pages it
-carries).
+carries), and wraps them in one `ops.dispatch` span (datapath/trace.py)
+that carries the kernel's name.
 """
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Optional, Tuple
 
@@ -66,10 +68,24 @@ def _resolve(backend: str) -> Tuple[str, bool]:
 
 _DISPATCHES = 0
 
+# Span hook: the repro.datapath.trace module, installed by the datapath
+# scheduler at its import time (kernels cannot import datapath).
+TRACE = None
+_NO_SPAN = contextlib.nullcontext()
 
-def _count(n: int = 1) -> None:
+
+def _dispatch(kernel: str, n: int = 1):
+    """Count `n` launches of `kernel`; returns the `ops.dispatch` span
+    that the launches run in."""
     global _DISPATCHES
     _DISPATCHES += n
+    t = TRACE
+    if t is None:
+        return _NO_SPAN
+    sp = t.span("ops.dispatch")
+    if sp is not t.NULL:
+        sp.set(kernel=kernel, n=n)
+    return sp
 
 
 def dispatch_count() -> int:
@@ -123,8 +139,8 @@ def device_put(buf) -> jax.Array:
     """Counted host->device transfer: PLAIN 'decode' is a device put, and
     the dispatch metric must see it on both the sequential path (one put
     per page) and the batched path (one put per stacked bucket)."""
-    _count()
-    return jnp.asarray(buf)
+    with _dispatch("device_put"):
+        return jnp.asarray(buf)
 
 
 # Jitted single-call reference paths.  The ref backend used to run these
@@ -156,45 +172,45 @@ def _ref_filter_compact_int(values, mask):
 def bitunpack(packed, k: int, n: Optional[int] = None, *, backend: str = "auto"):
     """(nblocks,k,128) uint32 -> flat (n,) int32 (or (nb,32,128) if n is None)."""
     backend, interp = _resolve(backend)
-    _count()
-    out = (
-        bitunpack_pallas(packed, k, interpret=interp)
-        if backend == "pallas"
-        else _ref_bitunpack_batch(packed, k)
-    )
+    with _dispatch("bitunpack"):
+        out = (
+            bitunpack_pallas(packed, k, interpret=interp)
+            if backend == "pallas"
+            else _ref_bitunpack_batch(packed, k)
+        )
     return out if n is None else out.reshape(-1)[:n]
 
 
 def dict_decode(packed, dictionary, k: int, n: Optional[int] = None, *, backend="auto"):
     backend, interp = _resolve(backend)
-    _count()
-    out = (
-        dict_decode_pallas(packed, dictionary, k, interpret=interp)
-        if backend == "pallas"
-        else _ref_dict_decode(packed, dictionary, k)
-    )
+    with _dispatch("dict_decode"):
+        out = (
+            dict_decode_pallas(packed, dictionary, k, interpret=interp)
+            if backend == "pallas"
+            else _ref_dict_decode(packed, dictionary, k)
+        )
     return out if n is None else out.reshape(-1)[:n]
 
 
 def rle_decode(values, ends, n: Optional[int] = None, *, backend="auto"):
     backend, interp = _resolve(backend)
-    _count()
-    out = (
-        rle_decode_pallas(values, ends, interpret=interp)
-        if backend == "pallas"
-        else _ref_rle_decode_batch(values, ends)
-    )
+    with _dispatch("rle_decode"):
+        out = (
+            rle_decode_pallas(values, ends, interpret=interp)
+            if backend == "pallas"
+            else _ref_rle_decode_batch(values, ends)
+        )
     return out if n is None else out.reshape(-1)[:n]
 
 
 def delta_decode(packed, bases, k: int, n: Optional[int] = None, *, backend="auto"):
     backend, interp = _resolve(backend)
-    _count()
-    out = (
-        delta_decode_pallas(packed, bases, k, interpret=interp)
-        if backend == "pallas"
-        else _ref_delta_decode_batch(packed, bases, k)
-    )
+    with _dispatch("delta_decode"):
+        out = (
+            delta_decode_pallas(packed, bases, k, interpret=interp)
+            if backend == "pallas"
+            else _ref_delta_decode_batch(packed, bases, k)
+        )
     return out if n is None else out.reshape(-1)[:n]
 
 
@@ -206,24 +222,24 @@ def filter_compact(values, mask, *, backend="auto"):
     """
     backend, interp = _resolve(backend)
     if jnp.issubdtype(values.dtype, jnp.integer):
-        # _count(2) on both backends: the pallas path launches two kernels,
-        # and the ref path prices the same two logical compactions even
-        # though jit fuses them into one executable
-        _count(2)
-        if backend != "pallas":
-            out, cnt = _ref_filter_compact_int(values, mask)
+        # two launches counted on both backends: the pallas path launches
+        # two kernels, and the ref path prices the same two logical
+        # compactions even though jit fuses them into one executable
+        with _dispatch("filter_compact", 2):
+            if backend != "pallas":
+                out, cnt = _ref_filter_compact_int(values, mask)
+                return out.astype(values.dtype), cnt
+            v = values.astype(jnp.int32)
+            hi16 = jax.lax.shift_right_arithmetic(v, 16)
+            lo16 = v & 0xFFFF
+            chi, cnt = filter_compact_pallas(hi16, mask, interpret=interp)
+            clo, _ = filter_compact_pallas(lo16, mask, interpret=interp)
+            out = jax.lax.shift_left(chi.astype(jnp.int32), 16) | clo.astype(jnp.int32)
             return out.astype(values.dtype), cnt
-        v = values.astype(jnp.int32)
-        hi16 = jax.lax.shift_right_arithmetic(v, 16)
-        lo16 = v & 0xFFFF
-        chi, cnt = filter_compact_pallas(hi16, mask, interpret=interp)
-        clo, _ = filter_compact_pallas(lo16, mask, interpret=interp)
-        out = jax.lax.shift_left(chi.astype(jnp.int32), 16) | clo.astype(jnp.int32)
-        return out.astype(values.dtype), cnt
-    _count()
-    if backend == "pallas":
-        return filter_compact_pallas(values, mask, interpret=interp)
-    return _ref_filter_compact(values, mask)
+    with _dispatch("filter_compact"):
+        if backend == "pallas":
+            return filter_compact_pallas(values, mask, interpret=interp)
+        return _ref_filter_compact(values, mask)
 
 
 def bloom_build(keys, n_bits: int, n_hashes: int = 4):
@@ -233,21 +249,21 @@ def bloom_build(keys, n_bits: int, n_hashes: int = 4):
 def bloom_probe(keys, bits, n_hashes: int = 4, *, backend="auto"):
     """keys (nblk,1024) -> membership (nblk,1024) bool."""
     backend, interp = _resolve(backend)
-    _count()
-    if backend == "pallas":
-        return bloom_probe_pallas(keys, bits, n_hashes=n_hashes, interpret=interp) > 0
-    return _ref_bloom_probe(keys, bits, n_hashes)
+    with _dispatch("bloom_probe"):
+        if backend == "pallas":
+            return bloom_probe_pallas(keys, bits, n_hashes=n_hashes, interpret=interp) > 0
+        return _ref_bloom_probe(keys, bits, n_hashes)
 
 
 def fused_scan(packed, k: int, lo, hi, dictionary=None, *, backend="auto"):
     backend, interp = _resolve(backend)
-    _count()
-    lo = jnp.asarray(lo, jnp.int32)
-    hi = jnp.asarray(hi, jnp.int32)
-    if backend == "pallas":
-        mask, cnt = fused_scan_pallas(packed, k, lo, hi, dictionary, interpret=interp)
-        return mask > 0, cnt
-    return _ref_fused_scan(packed, k, lo, hi, dictionary)
+    with _dispatch("fused_scan"):
+        lo = jnp.asarray(lo, jnp.int32)
+        hi = jnp.asarray(hi, jnp.int32)
+        if backend == "pallas":
+            mask, cnt = fused_scan_pallas(packed, k, lo, hi, dictionary, interpret=interp)
+            return mask > 0, cnt
+        return _ref_fused_scan(packed, k, lo, hi, dictionary)
 
 
 # ---------------------------------------------------------------------------
@@ -318,15 +334,15 @@ def bitunpack_batch(packed: np.ndarray, k: int, *, backend: str = "auto"):
     ONE dispatch.  `packed` is a host (numpy) stack; the leading axis is
     bucket-padded host-side so jit traces are reused."""
     backend, interp = _resolve(backend)
-    nb = packed.shape[0]
-    padded = _pad_blocks(packed, bucket_blocks(nb))
-    _count()
-    out = (
-        bitunpack_pallas(padded, k, interpret=interp)
-        if backend == "pallas"
-        else _ref_bitunpack_batch(padded, k)
-    )
-    return out[:nb]
+    with _dispatch("bitunpack_batch"):
+        nb = packed.shape[0]
+        padded = _pad_blocks(packed, bucket_blocks(nb))
+        out = (
+            bitunpack_pallas(padded, k, interpret=interp)
+            if backend == "pallas"
+            else _ref_bitunpack_batch(padded, k)
+        )
+        return out[:nb]
 
 
 def dict_decode_batch(
@@ -347,54 +363,54 @@ def dict_decode_batch(
     `dict_decode(packed_p, dicts[p, :sizes[p]], k)`.
     """
     backend, interp = _resolve(backend)
-    nb = packed.shape[0]
-    target = bucket_blocks(nb)
-    padded = _pad_blocks(packed, target)
-    page = _pad_blocks(np.asarray(page, np.int32), target)
-    d_blocks = np.ascontiguousarray(dicts[page])  # (nb_pad, Dmax)
-    s_blocks = np.asarray(sizes, np.int32)[page][:, None]  # (nb_pad, 1)
-    np.maximum(s_blocks, 1, out=s_blocks)
-    _count()
-    out = (
-        dict_decode_batch_pallas(padded, d_blocks, s_blocks, k, interpret=interp)
-        if backend == "pallas"
-        else _ref_dict_decode_batch(padded, d_blocks, s_blocks, k)
-    )
-    return out[:nb]
+    with _dispatch("dict_decode_batch"):
+        nb = packed.shape[0]
+        target = bucket_blocks(nb)
+        padded = _pad_blocks(packed, target)
+        page = _pad_blocks(np.asarray(page, np.int32), target)
+        d_blocks = np.ascontiguousarray(dicts[page])  # (nb_pad, Dmax)
+        s_blocks = np.asarray(sizes, np.int32)[page][:, None]  # (nb_pad, 1)
+        np.maximum(s_blocks, 1, out=s_blocks)
+        out = (
+            dict_decode_batch_pallas(padded, d_blocks, s_blocks, k, interpret=interp)
+            if backend == "pallas"
+            else _ref_dict_decode_batch(padded, d_blocks, s_blocks, k)
+        )
+        return out[:nb]
 
 
 def delta_decode_batch(packed: np.ndarray, bases: np.ndarray, k: int, *, backend="auto"):
     """Stacked (nblocks,k,128) zigzag deltas + (nblocks,) bases ->
     (nblocks,4096) int32 in ONE dispatch (blocks are self-contained)."""
     backend, interp = _resolve(backend)
-    nb = packed.shape[0]
-    target = bucket_blocks(nb)
-    padded = _pad_blocks(packed, target)
-    bases = _pad_blocks(np.asarray(bases, np.int32), target)
-    _count()
-    out = (
-        delta_decode_pallas(padded, bases, k, interpret=interp)
-        if backend == "pallas"
-        else _ref_delta_decode_batch(padded, bases, k)
-    )
-    return out[:nb]
+    with _dispatch("delta_decode_batch"):
+        nb = packed.shape[0]
+        target = bucket_blocks(nb)
+        padded = _pad_blocks(packed, target)
+        bases = _pad_blocks(np.asarray(bases, np.int32), target)
+        out = (
+            delta_decode_pallas(padded, bases, k, interpret=interp)
+            if backend == "pallas"
+            else _ref_delta_decode_batch(padded, bases, k)
+        )
+        return out[:nb]
 
 
 def rle_decode_batch(values: np.ndarray, ends: np.ndarray, *, backend="auto"):
     """Stacked (nblk,128) run values + ends -> (nblk,1024) in ONE dispatch
     (the writer clips runs at block boundaries, so blocks are independent)."""
     backend, interp = _resolve(backend)
-    nb = values.shape[0]
-    target = bucket_blocks(nb)
-    values = _pad_blocks(values, target)
-    ends = _pad_blocks(ends, target)
-    _count()
-    out = (
-        rle_decode_pallas(values, ends, interpret=interp)
-        if backend == "pallas"
-        else _ref_rle_decode_batch(values, ends)
-    )
-    return out[:nb]
+    with _dispatch("rle_decode_batch"):
+        nb = values.shape[0]
+        target = bucket_blocks(nb)
+        values = _pad_blocks(values, target)
+        ends = _pad_blocks(ends, target)
+        out = (
+            rle_decode_pallas(values, ends, interpret=interp)
+            if backend == "pallas"
+            else _ref_rle_decode_batch(values, ends)
+        )
+        return out[:nb]
 
 
 def fused_scan_batch(packed: np.ndarray, k: int, lo: np.ndarray, hi: np.ndarray,
@@ -405,17 +421,17 @@ def fused_scan_batch(packed: np.ndarray, k: int, lo: np.ndarray, hi: np.ndarray,
     DICT pages ride along: each row group's range is rewritten onto its
     own codes, so bounds differ across the stack."""
     backend, interp = _resolve(backend)
-    nb = packed.shape[0]
-    target = bucket_blocks(nb)
-    padded = _pad_blocks(packed, target)
-    lohi = np.stack([np.asarray(lo, np.int32), np.asarray(hi, np.int32)], axis=1)
-    lohi = _pad_blocks(lohi, target)
-    lohi[nb:, 0], lohi[nb:, 1] = 1, 0  # padded blocks match nothing
-    _count()
-    if backend == "pallas":
-        return fused_scan_batch_pallas(padded, k, jnp.asarray(lohi),
-                                       interpret=interp)[:nb] > 0
-    return _ref_fused_scan_batch(padded, lohi, k)[:nb]
+    with _dispatch("fused_scan_batch"):
+        nb = packed.shape[0]
+        target = bucket_blocks(nb)
+        padded = _pad_blocks(packed, target)
+        lohi = np.stack([np.asarray(lo, np.int32), np.asarray(hi, np.int32)], axis=1)
+        lohi = _pad_blocks(lohi, target)
+        lohi[nb:, 0], lohi[nb:, 1] = 1, 0  # padded blocks match nothing
+        if backend == "pallas":
+            return fused_scan_batch_pallas(padded, k, jnp.asarray(lohi),
+                                           interpret=interp)[:nb] > 0
+        return _ref_fused_scan_batch(padded, lohi, k)[:nb]
 
 
 def _pad_blocks_dev(arr, target: int):
@@ -436,18 +452,18 @@ def grouped_agg_batch(values, gids, mask, n_groups: int, *, backend="auto"):
     mask == 0 so their rows are exact merge identities."""
     assert 1 <= n_groups <= MAX_GROUPS, n_groups
     backend, interp = _resolve(backend)
-    nb = values.shape[0]
-    target = bucket_blocks(nb)
-    values = _pad_blocks_dev(values, target)
-    gids = _pad_blocks_dev(gids, target)
-    mask = _pad_blocks_dev(mask, target)
-    _count()
-    outs = (
-        grouped_agg_pallas(values, gids, mask, n_groups, interpret=interp)
-        if backend == "pallas"
-        else _ref_grouped_agg_batch(values, gids, mask, n_groups)
-    )
-    return tuple(o[:nb] for o in outs)
+    with _dispatch("grouped_agg_batch"):
+        nb = values.shape[0]
+        target = bucket_blocks(nb)
+        values = _pad_blocks_dev(values, target)
+        gids = _pad_blocks_dev(gids, target)
+        mask = _pad_blocks_dev(mask, target)
+        outs = (
+            grouped_agg_pallas(values, gids, mask, n_groups, interpret=interp)
+            if backend == "pallas"
+            else _ref_grouped_agg_batch(values, gids, mask, n_groups)
+        )
+        return tuple(o[:nb] for o in outs)
 
 
 def fused_agg_batch(packed: np.ndarray, k: int, mask, *, backend="auto"):
@@ -456,17 +472,17 @@ def fused_agg_batch(packed: np.ndarray, k: int, mask, *, backend="auto"):
     mask -> 5 x (nblocks, 1) accumulators.  The decoded value column
     never leaves the kernel (the pushdown headline path)."""
     backend, interp = _resolve(backend)
-    nb = packed.shape[0]
-    target = bucket_blocks(nb)
-    packed = _pad_blocks(packed, target)
-    mask = _pad_blocks_dev(mask, target)
-    _count()
-    outs = (
-        fused_agg_pallas(packed, k, mask, interpret=interp)
-        if backend == "pallas"
-        else _ref_fused_agg_batch(packed, mask, k)
-    )
-    return tuple(o[:nb] for o in outs)
+    with _dispatch("fused_agg_batch"):
+        nb = packed.shape[0]
+        target = bucket_blocks(nb)
+        packed = _pad_blocks(packed, target)
+        mask = _pad_blocks_dev(mask, target)
+        outs = (
+            fused_agg_pallas(packed, k, mask, interpret=interp)
+            if backend == "pallas"
+            else _ref_fused_agg_batch(packed, mask, k)
+        )
+        return tuple(o[:nb] for o in outs)
 
 
 def flash_attention(q, k, v, *, causal=True, window=None, scale=None, backend="auto",

@@ -16,7 +16,6 @@ import pytest
 
 from repro.core import BlockCache, Cmp, DatapathEngine, ScanPlan
 from repro.datapath import (
-    PAPER_FIG2_PCT,
     STAGES,
     DatapathService,
     StaticPolicy,
@@ -214,40 +213,48 @@ def test_attribution_maps_spans_and_never_double_bills():
     tr = Tracer(clock=clock)
     rt = tr.start(1, "t", "tbl")
     tr.begin(rt, "slice_dispatch")      # unmapped: recursed, not billed
-    tr.begin(rt, "fetch")
+    tr.begin(rt, "engine.storage_read")
     tr.event(rt, "store_hit")           # child of a mapped span: ignored
-    tr.end(rt, name="fetch")
-    tr.begin(rt, "decode_launch")
-    tr.end(rt, name="decode_launch")
-    tr.begin(rt, "filter")
-    tr.end(rt, name="filter")
+    tr.end(rt, name="engine.storage_read")
+    tr.begin(rt, "engine.decode")
+    tr.begin(rt, "ops.dispatch")        # child of a mapped span: ignored
+    tr.end(rt, name="ops.dispatch")
+    tr.end(rt, name="engine.decode")
+    tr.begin(rt, "engine.mask")
+    tr.end(rt, name="engine.mask")
     tr.end(rt, name="slice_dispatch")
     tr.finish(1, "done")
     sm = rt.summary
     assert set(sm["stages_s"]) == set(STAGES)
-    assert sm["stages_s"]["fetch"] > 0
-    assert sm["stages_s"]["decode"] > 0  # decode_launch -> decode
-    assert sm["stages_s"]["filter"] > 0
+    # FakeClock ticks 1 s a read: each mapped span is its own two reads
+    # apart, plus one read per span nested in it
+    assert sm["stages_s"]["fetch"] == 2.0
+    assert sm["stages_s"]["decode"] == 3.0  # engine.decode -> decode
+    assert sm["stages_s"]["filter"] == 1.0
     assert sm["stages_s"]["admission"] == 0.0
+    assert sm["attributed_s"] == sum(sm["stages_s"].values())
     assert sm["attributed_s"] <= sm["wall_s"] + 1e-12
-    assert 0.0 <= sm["decode_pct"] <= 100.0
-    assert abs(sm["decode_pct"] + sm["filter_pct"] + sm["rest_pct"] - 100.0) < 1e-9
+    assert not any(k.endswith("_pct") for k in sm)
 
 
 def test_report_rolls_up_by_tenant_with_paper_anchor():
     tr = make_tracer()
     for i, tenant in enumerate(("alice", "alice", "bob")):
         rt = tr.start(i, tenant, "tbl")
-        tr.begin(rt, "decode_launch")
-        tr.end(rt, name="decode_launch")
+        tr.begin(rt, "engine.decode")
+        tr.end(rt, name="engine.decode")
         tr.finish(i, "done")
     rep = tr.report()
-    assert rep["paper_fig2_pct"] == dict(sorted(PAPER_FIG2_PCT.items()))
+    # host seconds per stage; no percentages beside the paper's device split
+    assert not [k for k in rep if k.endswith("_pct")]
     assert set(rep["by_tenant"]) == {"alice", "bob"}
     assert rep["by_tenant"]["alice"]["n"] == 2
+    assert rep["by_tenant"]["alice"]["stage_s"]["decode"] == 2.0
+    assert rep["by_tenant"]["bob"]["stage_s"]["decode"] == 1.0
+    assert rep["stage_s"]["decode"] == 3.0
     for bt in rep["by_tenant"].values():
-        assert abs(bt["decode_pct"] + bt["filter_pct"] + bt["rest_pct"]
-                   - 100.0) < 1e-9
+        assert list(bt) == ["n", "wall_s", "stage_s"]
+        assert sum(bt["stage_s"].values()) <= bt["wall_s"] + 1e-9
     # fleet wall is the sum of per-tenant walls
     assert abs(rep["wall_s"]
                - sum(bt["wall_s"] for bt in rep["by_tenant"].values())) < 1e-9
@@ -296,25 +303,27 @@ def test_chrome_trace_empty_ring():
 # ---------------------------------------------------------------------------
 
 def test_module_hooks_noop_without_slice_context():
-    assert trace_mod._CUR is None
+    assert trace_mod._CUR is None and not trace_mod.profiling()
     # must not raise, must not allocate a trace anywhere
-    trace_mod.begin("fetch")
-    trace_mod.event("store_hit")
-    trace_mod.end(name="fetch")
+    with trace_mod.span("engine.storage_read") as sp:
+        trace_mod.event("store_hit")
+    assert sp is None
+    assert trace_mod.span("engine.decode", pages=1) is trace_mod.NULL
 
 
 def test_module_hooks_record_into_published_slice():
     tr = make_tracer()
     rt = tr.start(1, "t", "tbl")
-    trace_mod.set_slice(tr, rt)
+    trace_mod.set_slice(tr, rt, 1)
     try:
-        trace_mod.begin("fetch", rg=0)
-        trace_mod.event("store_hit", tier="encoded")
-        trace_mod.end(name="fetch", nbytes=10)
+        with trace_mod.span("engine.storage_read", rg=0) as sp:
+            trace_mod.event("store_hit", tier="encoded")
+            sp.set(bytes=10)
     finally:
         trace_mod.set_slice(None, None)
     (fe,) = rt.root["children"]
-    assert fe["name"] == "fetch" and fe["args"]["nbytes"] == 10
+    assert fe["name"] == "engine.storage_read"
+    assert fe["args"] == {"rg": 0, "bytes": 10}
     assert fe["children"][0]["name"] == "store_hit"
     tr.finish(1, "done")
 
@@ -347,7 +356,7 @@ def test_service_traces_full_lifecycle(table):
         assert sm["done_tick"] >= sm["submitted_tick"]
     for names in names_by_req.values():
         assert {"request", "admission", "slice_dispatch",
-                "decode_launch"} <= names
+                "engine.decode"} <= names
     # the sliced multi-tick request waited in the WFQ queue at least once
     assert any("wfq_wait" in names for names in names_by_req.values())
 
