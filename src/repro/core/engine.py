@@ -40,12 +40,14 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import hashlib
 from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.core import agg as agg_merge
 from repro.core.cache import BlockCache
@@ -88,6 +90,30 @@ def _span(name: str):
     `if sp is not None`."""
     t = TRACE
     return _NO_SPAN if t is None else t.span(name)
+
+
+_SPLIT_TRACES = [0]  # times `_split_program`'s body ran: once per new trace
+
+
+@functools.partial(jax.jit, static_argnames=("L",))
+def _split_program(out, starts, sizes, L: int):
+    """Every page's (L,) column out of one bucket's stacked output, in ONE
+    dispatch: page i is the `sizes[i]` elements of the flattened output
+    from `starts[i]`, zero-filled to L (an RLE page's 1024-row blocks can
+    fall short of L) and truncated to L.  Starts and sizes are runtime
+    arrays, so the trace keys only on the output's shape and dtype, L and
+    the page count (which the caller pads to the `ops.bucket_blocks`
+    ladder): another row-group order replays the same program."""
+    _SPLIT_TRACES[0] += 1  # Python runs here only while jit traces
+    # L zeros past the end keep every slice in bounds: dynamic_slice
+    # would clamp a start that runs off the end, not fill
+    flat = jnp.concatenate([out.reshape(-1), jnp.zeros((L,), out.dtype)])
+    row = jnp.arange(L, dtype=jnp.int32)
+    zero = jnp.zeros((), out.dtype)
+    return tuple(
+        jnp.where(row < sizes[i], lax.dynamic_slice(flat, (starts[i],), (L,)), zero)
+        for i in range(starts.shape[0])
+    )
 
 
 @dataclasses.dataclass
@@ -1179,35 +1205,46 @@ class DatapathEngine:
                 mask = ops.fused_scan_batch(packed, k, lo, hi, backend=be)
                 bstats.kernel_launches += 1
                 bstats.batch_pad_blocks += ops.bucket_blocks(packed.shape[0]) - packed.shape[0]
-                with _span("engine.split") as sl:
-                    s = 0
-                    for b, it in zip(blocks, items):
-                        fmasks[(it["item"], it["rg"])] = mask[s:s + b].reshape(-1)[: it["L"]]
-                        s += b
-                    if sl is not None:
-                        sl.set(pages=len(items))
+                cols = self._split(mask, blocks, [it["L"] for it in items])
+                for it, m in zip(items, cols):
+                    fmasks[(it["item"], it["rg"])] = m
                 if sp is not None:
                     sp.set(bucket=f"fused/k{k}", pages=len(items), fused=True,
                            launches=1, pad_blocks=bstats.batch_pad_blocks - pad0)
         return decoded, fmasks
 
     @staticmethod
-    def _split_flat(out, items, blocks) -> Dict[tuple, jax.Array]:
-        """Slice one bucket's stacked decode back into per-page (L,)
-        columns, replicating the sequential pad-to-L / truncate-to-L."""
-        res = {}
+    def _split(out, blocks, lengths) -> List[jax.Array]:
+        """Cut one bucket's stacked output back into per-page (L,) columns,
+        bit-identical to the sequential pad-to-L / truncate-to-L: page i
+        owns the next `blocks[i]` rows of `out`'s leading axis and is
+        `lengths[i]` long.  One `_split_program` call per distinct L."""
+        per = out.size // out.shape[0]  # elements per leading-axis row
+        sizes = np.asarray(blocks, np.int64) * per
+        starts = np.cumsum(sizes) - sizes
+        by_len: Dict[int, List[int]] = {}
+        for i, L in enumerate(lengths):
+            by_len.setdefault(L, []).append(i)
+        cols: List[jax.Array] = [None] * len(lengths)
         with _span("engine.split") as sp:
-            s = 0
-            for b, it in zip(blocks, items):
-                flat = out[s:s + b].reshape(-1)
-                L = it["L"]
-                if flat.shape[0] < L:
-                    flat = jnp.pad(flat, (0, L - flat.shape[0]))
-                res[(it.get("item", 0), it["rg"], it["name"])] = flat[:L]
-                s += b
+            traces0 = _SPLIT_TRACES[0] if sp is not None else 0
+            for L, idx in by_len.items():
+                n = ops.bucket_blocks(len(idx))
+                st = np.zeros(n, np.int32)
+                sz = np.zeros(n, np.int32)
+                st[:len(idx)] = starts[idx]
+                sz[:len(idx)] = sizes[idx]
+                for i, col in zip(idx, _split_program(out, st, sz, L=L)):
+                    cols[i] = col
             if sp is not None:
-                sp.set(pages=len(items))
-        return res
+                sp.set(pages=len(lengths), programs=len(by_len),
+                       traces=_SPLIT_TRACES[0] - traces0)
+        return cols
+
+    def _split_items(self, out, items, blocks) -> Dict[tuple, jax.Array]:
+        """`_split`, keyed (item, rg, column) as `decoded` holds pages."""
+        cols = self._split(out, blocks, [it["L"] for it in items])
+        return {(it.get("item", 0), it["rg"], it["name"]): c for it, c in zip(items, cols)}
 
     def _decode_bucket(self, bkey, items, be, stats) -> Dict[tuple, jax.Array]:
         """One bucket's launch: the host stacks its pages (`engine.stack`),
@@ -1215,12 +1252,14 @@ class DatapathEngine:
         sliced back into pages (`engine.split`)."""
         kind = bkey[0]
         if kind == "plain":
-            # one host gather + ONE device put for the whole bucket (plain
-            # has no kernel, so there is no jit trace to keep shape-stable
-            # — no power-of-two padding, just the stacked transfer)
+            # one host gather + ONE device put for the whole bucket, padded
+            # to the bucket ladder in PACK_BLOCK rows (every L is a multiple)
+            # like the kernels' block axis, so the split program keys on the
+            # bucket and not on the raw page mix
             with _span("engine.stack") as sp:
-                total = sum(it["L"] for it in items)
-                buf = np.zeros((total,), dtype=np.dtype(bkey[1]))
+                lengths = [it["L"] for it in items]
+                nblk = sum(lengths) // PACK_BLOCK
+                buf = np.zeros((ops.bucket_blocks(nblk) * PACK_BLOCK,), dtype=np.dtype(bkey[1]))
                 s = 0
                 for it in items:
                     v = it["col"].buffers["plain"]
@@ -1230,15 +1269,8 @@ class DatapathEngine:
                     sp.set(bucket="/".join(str(p) for p in bkey), pages=len(items))
             out = ops.device_put(buf)
             stats.kernel_launches += 1
-            res = {}
-            with _span("engine.split") as sp:
-                s = 0
-                for it in items:
-                    res[(it.get("item", 0), it["rg"], it["name"])] = out[s:s + it["L"]]
-                    s += it["L"]
-                if sp is not None:
-                    sp.set(pages=len(items))
-            return res
+            stats.batch_pad_blocks += ops.bucket_blocks(nblk) - nblk
+            return self._split_items(out, items, lengths)
         stats.kernel_launches += 1
         with _span("engine.stack") as sp:
             if kind == "rle":
@@ -1283,7 +1315,7 @@ class DatapathEngine:
         else:  # rle
             out = ops.rle_decode_batch(values, ends, backend=be)
         stats.batch_pad_blocks += ops.bucket_blocks(sum(blocks)) - sum(blocks)
-        return self._split_flat(out, items, blocks)
+        return self._split_items(out, items, blocks)
 
     # ------------------------------------------------------------------
     # cross-request bucket stacking (DESIGN.md §15)

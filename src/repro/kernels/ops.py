@@ -19,7 +19,9 @@ stack along the leading block axis and decode in ONE device dispatch.
 Inputs are stacked host (numpy) buffers; the leading axis is padded to a
 two-size-ladder bucket (see `bucket_blocks`) BEFORE the jitted call, so
 the whole scan reuses a handful of compiled traces instead of re-tracing
-per row-group count.
+per row-group count.  Each returns the bucket-padded output whole: rows
+past the input's `nblocks` are padding, and a consumer that jits over the
+output (the engine's page split) keys on the bucket, not the raw count.
 
 Single-call entry points on the 'ref' backend route through jitted
 wrappers too: eager jnp issues one XLA executable per primitive, which
@@ -330,9 +332,10 @@ def _pad_blocks(arr: np.ndarray, target: int, fill=0) -> np.ndarray:
 
 
 def bitunpack_batch(packed: np.ndarray, k: int, *, backend: str = "auto"):
-    """Stacked (nblocks,k,128) uint32 pages -> (nblocks,32,128) int32 in
-    ONE dispatch.  `packed` is a host (numpy) stack; the leading axis is
-    bucket-padded host-side so jit traces are reused."""
+    """Stacked (nblocks,k,128) uint32 pages -> (bucket,32,128) int32 in
+    ONE dispatch, rows past nblocks padding.  `packed` is a host (numpy)
+    stack; the leading axis is bucket-padded host-side so jit traces are
+    reused."""
     backend, interp = _resolve(backend)
     with _dispatch("bitunpack_batch"):
         nb = packed.shape[0]
@@ -342,7 +345,7 @@ def bitunpack_batch(packed: np.ndarray, k: int, *, backend: str = "auto"):
             if backend == "pallas"
             else _ref_bitunpack_batch(padded, k)
         )
-        return out[:nb]
+        return out
 
 
 def dict_decode_batch(
@@ -359,8 +362,8 @@ def dict_decode_batch(
     packed (nblocks,k,128) uint32 stacked codes; dicts (P, Dmax) page
     dictionaries padded to a common width; sizes (P,) true lengths;
     page (nblocks,) block -> source-page index.  Returns
-    (nblocks,32,128) values of dicts.dtype, bit-identical per page to
-    `dict_decode(packed_p, dicts[p, :sizes[p]], k)`.
+    (bucket,32,128) values of dicts.dtype, rows past nblocks padding, the
+    rest bit-identical per page to `dict_decode(packed_p, dicts[p, :sizes[p]], k)`.
     """
     backend, interp = _resolve(backend)
     with _dispatch("dict_decode_batch"):
@@ -376,12 +379,13 @@ def dict_decode_batch(
             if backend == "pallas"
             else _ref_dict_decode_batch(padded, d_blocks, s_blocks, k)
         )
-        return out[:nb]
+        return out
 
 
 def delta_decode_batch(packed: np.ndarray, bases: np.ndarray, k: int, *, backend="auto"):
     """Stacked (nblocks,k,128) zigzag deltas + (nblocks,) bases ->
-    (nblocks,4096) int32 in ONE dispatch (blocks are self-contained)."""
+    (bucket,4096) int32 in ONE dispatch, rows past nblocks padding
+    (blocks are self-contained)."""
     backend, interp = _resolve(backend)
     with _dispatch("delta_decode_batch"):
         nb = packed.shape[0]
@@ -393,12 +397,13 @@ def delta_decode_batch(packed: np.ndarray, bases: np.ndarray, k: int, *, backend
             if backend == "pallas"
             else _ref_delta_decode_batch(padded, bases, k)
         )
-        return out[:nb]
+        return out
 
 
 def rle_decode_batch(values: np.ndarray, ends: np.ndarray, *, backend="auto"):
-    """Stacked (nblk,128) run values + ends -> (nblk,1024) in ONE dispatch
-    (the writer clips runs at block boundaries, so blocks are independent)."""
+    """Stacked (nblk,128) run values + ends -> (bucket,1024) in ONE
+    dispatch, rows past nblk padding (the writer clips runs at block
+    boundaries, so blocks are independent)."""
     backend, interp = _resolve(backend)
     with _dispatch("rle_decode_batch"):
         nb = values.shape[0]
@@ -410,14 +415,14 @@ def rle_decode_batch(values: np.ndarray, ends: np.ndarray, *, backend="auto"):
             if backend == "pallas"
             else _ref_rle_decode_batch(values, ends)
         )
-        return out[:nb]
+        return out
 
 
 def fused_scan_batch(packed: np.ndarray, k: int, lo: np.ndarray, hi: np.ndarray,
                      *, backend="auto"):
     """Batched fused decode+filter: stacked (nblocks,k,128) pages with
     PER-BLOCK int bounds lo/hi (nblocks,) -> survivor mask
-    (nblocks,4096) bool in ONE dispatch.  Per-block bounds are what let
+    (bucket,4096) bool in ONE dispatch, padded rows all False.  Per-block bounds are what let
     DICT pages ride along: each row group's range is rewritten onto its
     own codes, so bounds differ across the stack."""
     backend, interp = _resolve(backend)
@@ -429,9 +434,8 @@ def fused_scan_batch(packed: np.ndarray, k: int, lo: np.ndarray, hi: np.ndarray,
         lohi = _pad_blocks(lohi, target)
         lohi[nb:, 0], lohi[nb:, 1] = 1, 0  # padded blocks match nothing
         if backend == "pallas":
-            return fused_scan_batch_pallas(padded, k, jnp.asarray(lohi),
-                                           interpret=interp)[:nb] > 0
-        return _ref_fused_scan_batch(padded, lohi, k)[:nb]
+            return fused_scan_batch_pallas(padded, k, jnp.asarray(lohi), interpret=interp) > 0
+        return _ref_fused_scan_batch(padded, lohi, k)
 
 
 def _pad_blocks_dev(arr, target: int):

@@ -27,6 +27,7 @@ from repro.core import BlockCache, Cmp, DatapathEngine, ScanPlan, tpch
 from repro.core.engine import ScanStats
 from repro.datapath import CostModel, DatapathService, StaticPolicy
 from repro.kernels import ops
+from repro.lakeformat.encodings import RLE_OUT_BLOCK, padded_rows
 from repro.lakeformat.reader import LakeReader
 from repro.lakeformat.schema import ColumnSchema, TableSchema
 from repro.lakeformat.writer import write_table
@@ -215,6 +216,137 @@ def test_batched_identical_with_cache_residency(mixed):
         seq, bat = _run_pair(mixed, plan, offload="preloaded", caches=caches)
         if density == 1.0:
             assert bat.stats.encoded_bytes == seq.stats.encoded_bytes == 0
+
+
+# ---------------------------------------------------------------------------
+# the bucket split: one compiled program per bucket, page for page
+# ---------------------------------------------------------------------------
+
+# one bucket kind per plan: the projected columns' encodings, or the fused
+# mask (predicate column not projected, so its pages never decode), with
+# the pages a row group sends through the split
+SPLIT_PLANS = {
+    "plain": (ScanPlan("mixed", ["price"]), 1),
+    "bitpack": (ScanPlan("mixed", ["key"]), 1),
+    "dict": (ScanPlan("mixed", ["cat"]), 1),
+    "delta": (ScanPlan("mixed", ["ts"]), 1),
+    "rle": (ScanPlan("mixed", ["flag", "level"]), 2),  # an int and a float bucket
+    "fused": (ScanPlan("mixed", ["price"], Cmp("key", "le", 1000)), 2),  # mask + price
+}
+
+
+def _pages(reader, plan, rgs, entry):
+    """[(cols, mask)] per row group of `rgs`, through the named batched
+    entry: `scan_row_groups_batched` over the whole list, or
+    `scan_group_batched` with the list split between two requests."""
+    eng = DatapathEngine(backend="ref", offload="raw", cache=BlockCache(1 << 30))
+    if entry == "row_groups":
+        rs = eng.resumable_scan(reader, plan)
+        per_rg, _ = eng.scan_row_groups_batched(
+            reader, rgs, rs.plan, rs.pred, rs.blooms, rs.stats)
+        return per_rg
+    cut = len(rgs) // 2
+    items = []
+    for part in (rgs[:cut], rgs[cut:]):
+        rs = eng.resumable_scan(reader, plan)
+        items.append({"reader": reader, "rgs": part, "plan": rs.plan, "pred": rs.pred,
+                      "blooms": rs.blooms, "stats": rs.stats, "offload": None})
+    return [p for per_rg, _ in eng.scan_group_batched(items) for p in per_rg]
+
+
+def _assert_pages_identical(got, want):
+    (gcols, gmask), (wcols, wmask) = got, want
+    assert gmask.dtype == wmask.dtype
+    assert np.array_equal(np.asarray(gmask), np.asarray(wmask))
+    assert set(gcols) == set(wcols)
+    for name, w in wcols.items():
+        if w is None:
+            assert gcols[name] is None, name
+            continue
+        assert gcols[name].dtype == w.dtype and gcols[name].shape == w.shape, name
+        assert np.array_equal(np.asarray(gcols[name]), np.asarray(w)), name
+
+
+@pytest.mark.parametrize("entry", ["row_groups", "group"])
+@pytest.mark.parametrize("kind", sorted(SPLIT_PLANS))
+def test_split_pages_match_sequential(mixed, monkeypatch, kind, entry):
+    """Each bucket kind, split by the compiled program, equals the
+    sequential per-row-group scan page for page and bit for bit —
+    including the short last row group (its own L, its own program call)
+    and RLE pages whose blocks fall short of L (zero-filled)."""
+    from repro.core import engine as engine_mod
+
+    plan, per_rg = SPLIT_PLANS[kind]
+    rgs = list(range(mixed.n_row_groups))
+    lengths = {padded_rows(mixed.row_group_meta(rg)["n"]) for rg in rgs}
+    assert len(lengths) == 2  # the ragged last group has an L of its own
+    if kind == "rle":
+        short = [rg for rg in rgs
+                 if mixed.read_encoded(rg, ["flag"])["flag"].buffers["rle_values"].shape[0]
+                 * RLE_OUT_BLOCK < padded_rows(mixed.row_group_meta(rg)["n"])]
+        assert short  # some RLE page is zero-filled up to L
+
+    calls = []
+    split = engine_mod.DatapathEngine._split
+
+    def spy(out, blocks, lengths):
+        calls.append(len(lengths))
+        return split(out, blocks, lengths)
+
+    monkeypatch.setattr(engine_mod.DatapathEngine, "_split", staticmethod(spy))
+    got = _pages(mixed, plan, rgs, entry)
+    assert sum(calls) == len(rgs) * per_rg
+
+    eng = DatapathEngine(backend="ref", offload="raw", cache=BlockCache(1 << 30))
+    rs = eng.resumable_scan(mixed, plan)
+    for rg, page in zip(rgs, got):
+        want = eng.scan_row_group(mixed, rg, rs.plan, rs.pred, rs.blooms, rs.stats)
+        _assert_pages_identical(page, want)
+
+
+def test_split_keeps_its_trace_across_row_group_order(mixed):
+    """A second pass over the same row groups in another order replays
+    the split programs of the first: the offsets are runtime arrays."""
+    from repro.core.engine import _SPLIT_TRACES
+
+    plan = MIXED_PLANS[0]
+    first = _pages(mixed, plan, [0, 1, 2, 3], "row_groups")
+    n0 = _SPLIT_TRACES[0]
+    order = [3, 1, 0, 2]
+    second = _pages(mixed, plan, order, "row_groups")
+    assert _SPLIT_TRACES[0] == n0
+    for rg, page in zip(order, second):
+        _assert_pages_identical(page, first[rg])
+
+
+@pytest.mark.parametrize("dtype", [np.int32, np.float32, np.bool_])
+def test_split_keeps_its_trace_within_a_bucket_rung(dtype):
+    """Five pages and six pad to the same rung of the page ladder, so the
+    six replay the program the five called; each page equals its slice
+    of the stacked output, zero-filled past its blocks and cut at L."""
+    import jax.numpy as jnp
+
+    from repro.core.engine import _SPLIT_TRACES
+
+    L, per = 8192, 1024
+    assert ops.bucket_blocks(5) == ops.bucket_blocks(6) == 6
+    rng = np.random.default_rng(11)
+    host = rng.integers(-5, 5, size=(48, per)).astype(dtype)  # 48 blocks of 1024
+    out = jnp.asarray(host)
+    for pages in (5, 6):
+        blocks = [6, 8, 9, 7, 8, 6][:pages]  # 6 blocks short of L, 9 past it
+        n0 = _SPLIT_TRACES[0]
+        cols = DatapathEngine._split(out, blocks, [L] * pages)
+        if pages == 6:  # the five-page call has traced (or found) the rung
+            assert _SPLIT_TRACES[0] == n0
+        flat, s = host.reshape(-1), 0
+        for b, col in zip(blocks, cols):
+            want = np.zeros(L, dtype)
+            n = min(b * per, L)
+            want[:n] = flat[s:s + n]
+            assert col.dtype == want.dtype
+            assert np.array_equal(np.asarray(col), want)
+            s += b * per
 
 
 # ---------------------------------------------------------------------------

@@ -57,7 +57,7 @@ def _check_rle(vals: np.ndarray, ends: np.ndarray):
     want = E.rle_decode_np({"rle_values": vals, "rle_ends": ends},
                            nb * RLE_OUT_BLOCK).reshape(nb, RLE_OUT_BLOCK)
     for be in BACKENDS:
-        got = np.asarray(ops.rle_decode_batch(vals, ends, backend=be))
+        got = np.asarray(ops.rle_decode_batch(vals, ends, backend=be))[:nb]
         assert got.dtype == want.dtype, be
         assert np.array_equal(got, want), be
     # single-call path (jitted ref wrapper)
@@ -81,7 +81,7 @@ def _rand_delta(rng, nb: int, k: int):
 
 def _check_delta(packed: np.ndarray, bases: np.ndarray, k: int, want: np.ndarray):
     for be in BACKENDS:
-        got = np.asarray(ops.delta_decode_batch(packed, bases, k, backend=be))
+        got = np.asarray(ops.delta_decode_batch(packed, bases, k, backend=be))[:len(want)]
         assert np.array_equal(got, want), (be, k)
 
 
@@ -105,7 +105,7 @@ def _rand_dict(rng, nb: int, k: int, float_vals: bool):
 def _check_dict(packed, dicts, sizes, page, k: int, want):
     for be in BACKENDS:
         got = np.asarray(
-            ops.dict_decode_batch(packed, dicts, sizes, page, k, backend=be))
+            ops.dict_decode_batch(packed, dicts, sizes, page, k, backend=be))[:len(want)]
         assert got.dtype == want.dtype, (be, k)
         assert np.array_equal(got, want), (be, k)
 
@@ -173,7 +173,7 @@ def test_bitunpack_parity_full_k_range():
         packed = E.bitpack_encode(v, k)
         want = np.asarray(ref.bitunpack(jnp.asarray(packed), k))
         for be in BACKENDS:
-            got = np.asarray(ops.bitunpack_batch(packed, k, backend=be))
+            got = np.asarray(ops.bitunpack_batch(packed, k, backend=be))[:len(want)]
             assert np.array_equal(got, want), (be, k)
 
 

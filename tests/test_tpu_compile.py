@@ -85,3 +85,21 @@ def test_kernel_compiles_for_v5e(one_chip, name, nb):
     compiled = jax.jit(fn).lower(*shapes).compile()
     assert "tpu_custom_call" in compiled.as_text(), name
     assert compiled.memory_analysis() is not None
+
+
+# the engine's page split at lineitem's page lengths: (stacked output
+# shape, dtype, L, pages) — a bitpack, an RLE float and a plain bucket of
+# full 65,536-row groups, and a fused mask for the short last group alone
+SPLITS = [((96, 32, 128), i32, 65536, 6), ((384, 1024), f32, 65536, 6),
+          ((96, 4096), jnp.bool_, 36864, 1), ((96 * 65536,), f32, 65536, 96)]
+
+
+@pytest.mark.parametrize("shape,dtype,L,pages", SPLITS)
+def test_split_program_compiles_for_v5e(one_chip, shape, dtype, L, pages):
+    from repro.core.engine import _split_program
+
+    out = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    idx = jax.ShapeDtypeStruct((pages,), i32, sharding=one_chip)
+    compiled = _split_program.lower(out, idx, idx, L=L).compile()
+    assert len(compiled.out_info) == pages
+    assert compiled.memory_analysis() is not None

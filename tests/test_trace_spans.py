@@ -136,6 +136,22 @@ def test_spans_land_in_the_profiler_trace_nested(table, tmp_path):
     assert all(t0 <= t1 for _, _, t0, t1, _ in log.spans)
 
 
+def test_split_span_counts_pages_programs_and_traces(table, tmp_path):
+    """Each `engine.split` span carries the pages it cut, the split
+    programs it called (one per page length in the bucket) and the new
+    traces among them; a second serve of the same scans replays every
+    program, so it traces none."""
+    serve(table, rate=0.0)
+    with jax.profiler.trace(str(tmp_path)):
+        tickets = serve(table, rate=0.0)
+    assert all(t.status == "done" for t in tickets)
+    splits = [c for n, _, _, _, c in trace.span_log().spans if n == "engine.split"]
+    assert splits
+    for c in splits:
+        assert c["pages"] >= c["programs"] >= 1
+        assert c["traces"] == 0
+
+
 def test_span_log_keeps_one_session_and_counts_drops(monkeypatch, tmp_path):
     monkeypatch.setattr(trace, "LOG", trace.SpanLog(capacity=3))
     with jax.profiler.trace(str(tmp_path / "a")):
