@@ -42,6 +42,7 @@ import contextlib
 import dataclasses
 import functools
 import hashlib
+import operator
 from typing import Dict, List, Optional
 
 import jax
@@ -116,6 +117,93 @@ def _split_program(out, starts, sizes, L: int):
     )
 
 
+_MASK_TRACES = [0]  # times `_mask_program`'s body ran: once per new trace
+
+@functools.partial(jax.jit, static_argnames=("shape", "L"))
+def _mask_program(cols, lits, hits, n, shape, L: int):
+    """A row group's (L,) mask, `predicate & (iota(L) < n)`, in ONE
+    dispatch.  `shape` is the predicate with its literals taken out
+    (`_mask_shape`), or None for validity alone; its leaves index `cols`,
+    `lits` (device scalars, weakly typed as the eager comparison's
+    literals are, so each comparison computes in the same type) and
+    `hits` (bloom hit masks, or the fused scan's mask).  Every value is a
+    runtime operand, so a new parameter draw replays the program: the
+    trace keys on the shape, L and the operands' shapes and dtypes."""
+    _MASK_TRACES[0] += 1  # Python runs here only while jit traces
+
+    def ev(node):
+        kind = node[0]
+        if kind == "cmp":
+            _, c, op, slots = node
+            v = cols[c]
+            if op == "between":
+                return (v >= lits[slots[0]]) & (v <= lits[slots[1]])
+            return getattr(operator, op)(v, lits[slots[0]])  # Cmp's ops are operator's
+        if kind == "in":
+            m = jnp.zeros((L,), jnp.bool_)
+            for s in node[2]:
+                m = m | (cols[node[1]] == lits[s])
+            return m
+        if kind == "hit":
+            return hits[node[1]].reshape(-1)[:L]
+        m = ev(node[1][0])
+        for c in node[1][1:]:
+            m = (m & ev(c)) if kind == "and" else (m | ev(c))
+        return m
+
+    valid = lax.iota(jnp.int32, L) < n
+    return valid if shape is None else ev(shape) & valid
+
+
+def _mask_shape(pred: Expr):
+    """`pred` as `_mask_program` runs it: (shape, columns, literals,
+    probes).  Leaves become ("cmp", column slot, op, literal slots),
+    ("in", column slot, literal slots) and ("hit", probe slot), inner
+    nodes ("and"|"or", children); `columns`, `literals` (the values) and
+    `probes` (the BloomProbe leaves) list the slots in order.  Not
+    cached on `pred`: `==` on predicates takes 1 for 1.0, whose
+    comparisons compute in other types."""
+    columns: List[str] = []
+    literals: List = []
+    probes: List[BloomProbe] = []
+
+    def slot(column):
+        if column not in columns:
+            columns.append(column)
+        return columns.index(column)
+
+    def lit(value):
+        literals.append(value)
+        return len(literals) - 1
+
+    def walk(e):
+        if isinstance(e, Cmp):
+            vals = e.value if e.op == "between" else (e.value,)
+            return ("cmp", slot(e.column), e.op, tuple(lit(v) for v in vals))
+        if isinstance(e, InSet):
+            return ("in", slot(e.column), tuple(lit(v) for v in e.values))
+        if isinstance(e, BloomProbe):
+            probes.append(e)
+            return ("hit", len(probes) - 1)
+        if isinstance(e, (And, Or)):
+            return ("and" if isinstance(e, And) else "or",
+                    tuple(walk(c) for c in e.children))
+        raise TypeError(e)
+
+    shape = walk(pred)
+    return shape, columns, literals, probes
+
+
+@functools.lru_cache(maxsize=1024, typed=True)
+def _operand(value) -> jax.Array:
+    """A literal or row count as a device scalar, built once and not once
+    per row group; a Python number stays weakly typed, as in eager jnp."""
+    return jax.device_put(value)
+
+
+_GIVEN = ("hit", 0)  # `_mask_program`'s shape for a mask computed elsewhere
+
+
 @dataclasses.dataclass
 class ScanStats:
     row_groups_total: int = 0
@@ -188,20 +276,6 @@ class ScanResult:
     # aggregate scans (nothing row-shaped crosses the result DMA).
     aggregates: Optional[Dict[str, np.ndarray]] = None
     agg_partials: Optional[Dict[int, dict]] = None
-
-
-def _expr_blooms(e: Optional[Expr]) -> List[BloomProbe]:
-    """Every BloomProbe node in a predicate tree, document order."""
-    if e is None:
-        return []
-    if isinstance(e, BloomProbe):
-        return [e]
-    if isinstance(e, (And, Or)):
-        out: List[BloomProbe] = []
-        for c in e.children:
-            out.extend(_expr_blooms(c))
-        return out
-    return []
 
 
 def group_domain(reader, column: str) -> int:
@@ -384,77 +458,53 @@ class DatapathEngine:
         return arr, False
 
     # ------------------------------------------------------------------
-    # predicate evaluation (on decoded device columns)
+    # predicate and row validity (on decoded device columns)
     # ------------------------------------------------------------------
-    def _eval(self, e: Expr, cols: Dict[str, jax.Array], blooms: Dict[str, jax.Array],
-              bmasks: Optional[Dict] = None):
-        if isinstance(e, Cmp):
-            v = cols[e.column]
-            if e.op == "between":
-                lo, hi = e.value
-                return (v >= lo) & (v <= hi)
-            val = e.value
-            return {
-                "lt": v < val,
-                "le": v <= val,
-                "gt": v > val,
-                "ge": v >= val,
-                "eq": v == val,
-                "ne": v != val,
-            }[e.op]
-        if isinstance(e, InSet):
-            v = cols[e.column]
-            m = jnp.zeros(v.shape, jnp.bool_)
-            for val in e.values:
-                m = m | (v == val)
-            return m
-        if isinstance(e, BloomProbe):
-            # the batched bucket pass pre-probes every slice page's keys in
-            # ONE stacked ops.bloom_probe per filter (`_batch_bloom_probe`)
-            # and hands the per-row-group slice down here — bit-identical
-            # (the probe is elementwise per key), one dispatch instead of
-            # one per row group
-            if bmasks is not None:
-                hit = bmasks.get((e.name, e.column))
-                if hit is not None:
-                    return hit
-            keys = cols[e.column].astype(jnp.int32)
-            L = keys.shape[0]
-            pad = (-L) % RLE_OUT_BLOCK
-            if pad:
-                keys = jnp.pad(keys, (0, pad))
-            m = ops.bloom_probe(
-                keys.reshape(-1, RLE_OUT_BLOCK),
-                blooms[e.name],
-                e.n_hashes,
-                backend=self.backend if self.backend != "host" else "ref",
-            )
-            return m.reshape(-1)[:L]
-        if isinstance(e, And):
-            m = self._eval(e.children[0], cols, blooms, bmasks)
-            for c in e.children[1:]:
-                m = m & self._eval(c, cols, blooms, bmasks)
-            return m
-        if isinstance(e, Or):
-            m = self._eval(e.children[0], cols, blooms, bmasks)
-            for c in e.children[1:]:
-                m = m | self._eval(c, cols, blooms, bmasks)
-            return m
-        raise TypeError(e)
-
-    def _eval_mask(self, pred: Optional[Expr], cols, blooms, L: int, rg: int,
-                   bmasks: Optional[Dict] = None):
-        """Predicate eval wrapped in an `engine.mask` span (no predicate:
-        an all-true validity mask, not filter work, so no span).  `bmasks`
-        maps (bloom name, column) -> this row group's pre-probed (L,)
-        membership mask from the batched path's stacked probe."""
-        if pred is None:
-            return jnp.ones((L,), jnp.bool_)
+    def _row_mask(self, pred: Optional[Expr], cols, blooms, n: int, L: int, rg: int,
+                  bmasks: Optional[Dict] = None, given: Optional[jax.Array] = None):
+        """A row group's (L,) mask, `pred & (iota(L) < n)`, in one
+        `_mask_program` call.  `given` is a mask computed elsewhere (the
+        fused scan's) that stands in for `pred`; with neither the mask is
+        validity alone, and neither is filter work, so neither opens a
+        span.  `bmasks` maps (bloom name, column) -> this row group's
+        pre-probed (L,) membership mask from the batched path's stacked
+        probe; a BloomProbe leaf without one is probed here."""
+        if given is not None or pred is None:
+            return _mask_program((), (), () if given is None else (given,), _operand(int(n)),
+                                 shape=None if given is None else _GIVEN, L=L)
         with _span("engine.mask") as sp:
-            mask = self._eval(pred, cols, blooms, bmasks)
+            traces0 = _MASK_TRACES[0] if sp is not None else 0
+            shape, columns, literals, probes = _mask_shape(pred)
+            mask = _mask_program(
+                tuple(cols[c] for c in columns), tuple(_operand(v) for v in literals),
+                tuple(self._bloom_hits(p, cols, blooms, bmasks) for p in probes),
+                _operand(int(n)), shape=shape, L=L)
             if sp is not None:
-                sp.set(rg=rg, rows=L)
+                sp.set(rg=rg, rows=L, programs=1, traces=_MASK_TRACES[0] - traces0)
         return mask
+
+    def _bloom_hits(self, e: BloomProbe, cols, blooms, bmasks: Optional[Dict]):
+        """A BloomProbe leaf's (L,) hit mask: the batched bucket pass
+        pre-probes every slice page's keys in ONE stacked ops.bloom_probe
+        per filter (`_batch_bloom_probe`) and hands the per-row-group slice
+        down here — bit-identical (the probe is elementwise per key), one
+        dispatch instead of one per row group."""
+        if bmasks is not None:
+            hit = bmasks.get((e.name, e.column))
+            if hit is not None:
+                return hit
+        keys = cols[e.column].astype(jnp.int32)
+        L = keys.shape[0]
+        pad = (-L) % RLE_OUT_BLOCK
+        if pad:
+            keys = jnp.pad(keys, (0, pad))
+        m = ops.bloom_probe(
+            keys.reshape(-1, RLE_OUT_BLOCK),
+            blooms[e.name],
+            e.n_hashes,
+            backend=self.backend if self.backend != "host" else "ref",
+        )
+        return m.reshape(-1)[:L]
 
     # ------------------------------------------------------------------
     # fused decode+filter fast path
@@ -540,7 +590,7 @@ class DatapathEngine:
         remaining fields are empty — no encoded byte moves.  Fusable plans
         never take the shortcut (their predicate column is never decoded,
         so its key can never be resident), which keeps the resident mask
-        an `_eval` over exactly the arrays a direct scan would produce.
+        a `_row_mask` over exactly the arrays a direct scan would produce.
         """
         need = plan.all_columns()
         n = reader.row_group_meta(rg)["n"]
@@ -839,9 +889,7 @@ class DatapathEngine:
                     reader, rg, name, None, L, offload=offload, pool=pool, stats=stats
                 )
                 cols[name] = arr
-            mask = self._eval_mask(pred, cols, blooms, L, rg)
-            mask = mask & (jnp.arange(L) < n)
-            return cols, mask
+            return cols, self._row_mask(pred, cols, blooms, n, L, rg)
 
         askip = self._agg_skip(plan, pred, enc)
         cols: Dict[str, Optional[jax.Array]] = {}
@@ -867,7 +915,6 @@ class DatapathEngine:
                 )
                 if sp is not None:
                     sp.set(rg=rg, encoding=fe, fused=True, pages=1, rows=L)
-            fmask = fmask.reshape(-1)[:L]
             for name in proj:
                 if name in askip:
                     self._charge_agg_page(stats, enc[name], L)
@@ -877,7 +924,7 @@ class DatapathEngine:
                     reader, rg, name, enc[name], L, offload=offload, pool=pool, stats=stats
                 )
                 cols[name] = arr
-            mask = fmask
+            mask = self._row_mask(pred, cols, blooms, n, L, rg, given=fmask)
         else:
             for name in need:
                 if name in askip:
@@ -888,9 +935,8 @@ class DatapathEngine:
                     reader, rg, name, enc[name], L, offload=offload, pool=pool, stats=stats
                 )
                 cols[name] = arr
-            mask = self._eval_mask(pred, cols, blooms, L, rg)
+            mask = self._row_mask(pred, cols, blooms, n, L, rg)
 
-        mask = mask & (jnp.arange(L) < n)  # row validity
         for name in need:
             cols.setdefault(name, None)  # predicate-only column under fusion
         return cols, mask
@@ -1002,8 +1048,7 @@ class DatapathEngine:
                         cols[name] = self._serve_resident(
                             reader, rg, name, L, mode, offload, pool, stats, fetched
                         )
-                    mask = self._eval_mask(pred, cols, blooms, L, rg)
-                    per_rg.append((cols, mask & (jnp.arange(L) < n)))
+                    per_rg.append((cols, self._row_mask(pred, cols, blooms, n, L, rg)))
                     continue
                 enc = slot["enc"]
                 askip = slot["askip"]
@@ -1025,7 +1070,8 @@ class DatapathEngine:
                             pool=pool, stats=stats, precomputed=decoded.get((0, rg, name)),
                         )
                         cols[name] = arr
-                    mask = fmasks[(0, rg)]
+                    mask = self._row_mask(pred, cols, blooms, n, L, rg,
+                                          given=fmasks[(0, rg)])
                 else:
                     for name in need:
                         if name in askip:
@@ -1037,9 +1083,8 @@ class DatapathEngine:
                             pool=pool, stats=stats, precomputed=decoded.get((0, rg, name)),
                         )
                         cols[name] = arr
-                    mask = self._eval_mask(pred, cols, blooms, L, rg,
-                                           bmasks=bloom_by_rg.get((0, rg)))
-                mask = mask & (jnp.arange(L) < n)
+                    mask = self._row_mask(pred, cols, blooms, n, L, rg,
+                                          bmasks=bloom_by_rg.get((0, rg)))
                 for name in need:
                     cols.setdefault(name, None)
                 per_rg.append((cols, mask))
@@ -1051,13 +1096,13 @@ class DatapathEngine:
         """Stack every freshly-decoded slice page's keys and probe each
         bloom filter in ONE `ops.bloom_probe` dispatch (the semijoin leg
         of the fused bucket pass).  Returns {(item, rg): {(name, column):
-        (L,) mask}} for `_eval` to consume; pages served from the pool or
+        (L,) mask}} for `_row_mask` to consume; pages served from the pool or
         cache at finalize time are absent and fall back to the per-row-
         group probe — bit-identical either way, the probe is elementwise.
         """
         if pred is None or self.backend == "host" or not blooms:
             return {}
-        probes = {(p.name, p.column): p for p in _expr_blooms(pred)
+        probes = {(p.name, p.column): p for p in _mask_shape(pred)[3]
                   if p.name in blooms}
         out: Dict[tuple, Dict] = {}
         with _span("engine.bloom") as sp:
@@ -1469,8 +1514,7 @@ class DatapathEngine:
                                 reader, rg, name, L, mode, offload, pool, stats,
                                 fetched_by_item[i],
                             )
-                        mask = self._eval_mask(pred, cols, blooms, L, rg)
-                        per_rg.append((cols, mask & (jnp.arange(L) < n)))
+                        per_rg.append((cols, self._row_mask(pred, cols, blooms, n, L, rg)))
                         continue
                     enc = slot["enc"]
                     askip = slot["askip"]
@@ -1493,7 +1537,8 @@ class DatapathEngine:
                                 precomputed=decoded.get((i, rg, name)),
                             )
                             cols[name] = arr
-                        mask = fmasks[(i, rg)]
+                        mask = self._row_mask(pred, cols, blooms, n, L, rg,
+                                              given=fmasks[(i, rg)])
                     else:
                         for name in need:
                             if name in askip:
@@ -1506,8 +1551,7 @@ class DatapathEngine:
                                 precomputed=decoded.get((i, rg, name)),
                             )
                             cols[name] = arr
-                        mask = self._eval_mask(pred, cols, blooms, L, rg)
-                    mask = mask & (jnp.arange(L) < n)
+                        mask = self._row_mask(pred, cols, blooms, n, L, rg)
                     for name in need:
                         cols.setdefault(name, None)
                     per_rg.append((cols, mask))

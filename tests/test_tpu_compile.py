@@ -103,3 +103,36 @@ def test_split_program_compiles_for_v5e(one_chip, shape, dtype, L, pages):
     compiled = _split_program.lower(out, idx, idx, L=L).compile()
     assert len(compiled.out_info) == pages
     assert compiled.memory_analysis() is not None
+
+
+# the engine's row-group mask at lineitem's row-group lengths: Q6's three
+# comparisons on a full group, Q19's bloom leaf, `between` and sets on the
+# short last group, and the trivial shapes (validity alone; a given mask)
+MASKS = [("q6", 65536), ("q19", 36864), ("validity", 65536), ("given", 65536)]
+
+
+@pytest.mark.parametrize("pred,L", MASKS)
+def test_mask_program_compiles_for_v5e(one_chip, pred, L):
+    from repro.core.engine import _GIVEN, _mask_program, _mask_shape, _operand
+    from repro.core.plan import And, BloomProbe, Cmp, InSet
+    from repro.core.queries import q6_plan
+
+    def spec(shape, dtype, weak=False):
+        return jax.ShapeDtypeStruct(shape, dtype, weak_type=weak, sharding=one_chip)
+
+    n = spec((), i32, weak=True)
+    if pred in ("validity", "given"):
+        hits = (spec((L,), jnp.bool_),) if pred == "given" else ()
+        shape = _GIVEN if pred == "given" else None
+        compiled = _mask_program.lower((), (), hits, n, shape=shape, L=L).compile()
+    else:
+        expr = q6_plan(365).predicate if pred == "q6" else And((
+            BloomProbe("l_partkey", name="q19"), Cmp("l_quantity", "between", (1, 30)),
+            InSet("l_shipinstruct", (0,)), InSet("l_shipmode", (2, 5))))
+        shape, columns, literals, probes = _mask_shape(expr)
+        cols = tuple(spec((L,), f32 if c == "l_discount" else i32) for c in columns)
+        lits = tuple(spec((), _operand(v).dtype, weak=True) for v in literals)
+        hits = tuple(spec((L,), jnp.bool_) for _ in probes)
+        compiled = _mask_program.lower(cols, lits, hits, n, shape=shape, L=L).compile()
+    assert compiled.out_info.shape == (L,) and compiled.out_info.dtype == jnp.bool_
+    assert compiled.memory_analysis() is not None

@@ -152,6 +152,38 @@ def test_split_span_counts_pages_programs_and_traces(table, tmp_path):
         assert c["traces"] == 0
 
 
+def test_mask_span_counts_programs_and_traces(table, tmp_path):
+    """Each `engine.mask` span carries the mask programs it called (one a
+    row group) and the new traces among them; a second serve of the same
+    scans replays every program, so it traces none."""
+    serve(table, rate=0.0)
+    with jax.profiler.trace(str(tmp_path)):
+        tickets = serve(table, rate=0.0)
+    assert all(t.status == "done" for t in tickets)
+    masks = [c for n, _, _, _, c in trace.span_log().spans if n == "engine.mask"]
+    assert masks
+    for c in masks:
+        assert c["programs"] == 1 and c["traces"] == 0
+        assert c["rows"] >= RG_ROWS
+
+
+def test_mask_program_is_not_a_counted_launch(table):
+    """The mask program is no kernel launch: a served scan dispatches as
+    many kernels with a predicate as without one, when the predicate's
+    columns are decoded either way."""
+    pred = Cmp("a", "lt", 3000)
+    launches = []
+    for p in (pred, None):
+        d0 = ops.dispatch_count()
+        svc = DatapathService(engine=DatapathEngine(backend="ref", cache=BlockCache(1 << 30)),
+                              policy=StaticPolicy("raw"))
+        tickets = [svc.submit("t", table, ScanPlan("spans", ["a", "b", "d"], p))]
+        svc.drain()
+        assert tickets[0].status == "done"
+        launches.append(ops.dispatch_count() - d0)
+    assert launches[0] == launches[1] > 0
+
+
 def test_span_log_keeps_one_session_and_counts_drops(monkeypatch, tmp_path):
     monkeypatch.setattr(trace, "LOG", trace.SpanLog(capacity=3))
     with jax.profiler.trace(str(tmp_path / "a")):
